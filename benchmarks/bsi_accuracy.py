@@ -42,7 +42,7 @@ def run(grid_pts=9, channels=3, tiles=None):
                 err = np.mean(np.abs(out - ref)) * 1e6
                 rows.append((f"bsi_accuracy/tile{t}/jnp_{mode}", 0.0,
                              f"{err:.3f}e-6"))
-            for mode in ("tt", "ttli", "separable", "matmul"):
+            for mode in ops.PALLAS_MODES:
                 out = np.asarray(
                     ops.bsi_pallas(phi32, tile, mode=mode), np.float64)
                 err = np.mean(np.abs(out - ref)) * 1e6

@@ -46,6 +46,7 @@ import numpy as np
 
 from benchmarks.common import FULL_VOLUMES, SCALED_VOLUMES, emit, grid_for, time_fn
 from repro.core import ffd
+from repro.kernels.ops import PALLAS_MODES
 
 TILES = [3, 4, 5, 6, 7]
 MODES = ["gather", "tt", "ttli", "separable", "matmul"]
@@ -100,6 +101,8 @@ def run_grad(full=False, volumes=("phantom2", "porcine1"), reps=3, tiles=None,
         tile = (t, t, t)
         for mode in (modes or MODES):
             for impl in impls:
+                if impl == "pallas" and mode not in PALLAS_MODES:
+                    continue  # no kernel (kernels.ops.NO_KERNEL says why)
                 base_t = None
                 for gi in (grad_impls or GRAD_IMPLS):
                     if impl == "pallas" and gi == "xla":

@@ -105,6 +105,10 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     preset = args.preset or ("full" if args.full else "default")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     results = {}
     failures = []
     for name, fn in _suites(preset):

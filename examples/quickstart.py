@@ -56,8 +56,10 @@ def main():
         print(f"  {mode:10s}: {dt*1e3:7.1f} ms   max|err vs oracle| = {err:.2e}")
 
     # --- 2. the same kernels in Pallas (TPU target, interpret mode on CPU)
-    out = ops.bsi_pallas(phi, tile, mode="ttli")
-    print(f"pallas ttli: max|err| = {float(jnp.max(jnp.abs(out - ref))):.2e}")
+    for mode in ops.PALLAS_MODES:
+        out = ops.bsi_pallas(phi, tile, mode=mode)
+        err = float(jnp.max(jnp.abs(out - ref)))
+        print(f"pallas {mode}: max|err| = {err:.2e}")
 
     # --- 3. generic image zoom (paper §8): pixels as control points
     img = jnp.asarray(rng.standard_normal((36, 36)), jnp.float32)

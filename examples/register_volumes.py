@@ -74,6 +74,10 @@ def main():
                          "and 0.5 overshoots for the first ~15 steps at "
                          "this scale)")
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.lr is None:
         args.lr = 0.12 if args.early_stop is not None else 0.5
         if args.early_stop is not None:

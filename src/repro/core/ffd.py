@@ -79,7 +79,7 @@ def dense_field(phi, tile, vol_shape, *, mode="separable", impl="jnp",
 
 def fused_warp_loss(phi, moving, fixed, tile, *, similarity="ssd",
                     mode="separable", impl="jnp", grad_impl="xla",
-                    compute_dtype=None, interpret=None):
+                    compute_dtype=None):
     """``sim(warp(moving, bsi(phi)), fixed)`` without a dense field in HBM.
 
     The differentiable face of the fused level step: the forward runs the
@@ -110,13 +110,12 @@ def fused_warp_loss(phi, moving, fixed, tile, *, similarity="ssd",
             "callables must run unfused (fused='off')")
     cd = None if compute_dtype is None else jnp.dtype(compute_dtype).name
     f = _fused_objective(tuple(int(t) for t in tile), tuple(spec),
-                         str(mode), str(impl), str(grad_impl), cd,
-                         None if interpret is None else bool(interpret))
+                         str(mode), str(impl), str(grad_impl), cd)
     return f(phi, moving, fixed)
 
 
 @functools.lru_cache(maxsize=None)
-def _fused_objective(tile, spec, mode, impl, grad_impl, cdtype, interpret):
+def _fused_objective(tile, spec, mode, impl, grad_impl, cdtype):
     from repro.core.similarity import _loss_from_spec
     from repro.kernels import ops
 
@@ -134,7 +133,6 @@ def _fused_objective(tile, spec, mode, impl, grad_impl, cdtype, interpret):
     def fused(p, mov, fix):
         return ops.fused_similarity_loss(p, mov, fix, tile, sim_spec=spec,
                                          compute_dtype=cdtype,
-                                         interpret=interpret,
                                          disp_form=disp_form)
 
     def fwd(p, mov, fix):

@@ -1,13 +1,11 @@
 """B-spline interpolation — public API and the jnp-level algorithm forms.
 
-Three algorithmic forms of paper Eq. (1), mirroring the paper's comparison
-matrix (§5), plus a mode dispatcher.  Each form exists twice in the repo:
-
-* here as a pure-jnp implementation — these are the *CPU analogs* (the paper's
-  Fig. 7 VT/VV role) and the reference semantics;
-* in ``repro.kernels`` as a Pallas TPU kernel with explicit VMEM tiling
-  (``bsi_tt``, ``bsi_ttli``, ``bsi_separable``) — the paper's GPU kernels,
-  adapted to TPU (DESIGN.md §2).
+The algorithmic forms of paper Eq. (1), mirroring the paper's comparison
+matrix (§5), plus a mode dispatcher.  Every form exists here as a pure-jnp
+implementation — the *CPU analogs* (the paper's Fig. 7 VT/VV role) and the
+reference semantics.  ``separable`` and ``matmul`` also exist in
+``repro.kernels`` as Pallas TPU kernels with explicit VMEM tiling
+(``kernels.ops.NO_KERNEL`` says why the other forms have none).
 
 Forms
 -----
@@ -40,12 +38,13 @@ share one analytic adjoint: the Tucker contraction run in reverse
 ``jnp``     ``jax.custom_vjp`` whose backward is the separable-transpose:
             each control point's cotangent is a weighted reduction over its
             own (4·tile)^3 support window — gather-only, three small matmuls.
-``pallas``  the same contraction as a VMEM-tiled TPU kernel
-            (``repro.kernels.bsi_adjoint``), thread-per-*control-point*.
-``matmul``  the transposed matrix form as a VMEM-tiled TPU kernel: one
-            ``(64, d^3) @ (d^3, tiles*C)`` MXU contraction per control block
-            followed by the 64-band shifted overlap-add (also in
-            ``repro.kernels.bsi_adjoint``).
+``pallas``  a VMEM-tiled TPU kernel (``repro.kernels.bsi_adjoint``), the
+            exact transpose of the separable forward kernel: x taps fold
+            the cotangent planes, then banded MXU matmuls project each
+            plane to the control grid.
+``matmul``  the transpose of the matmul forward kernel (same module): each
+            cotangent plane projects first, the x taps act on the small
+            control planes.
 
 Because BSI is linear, the custom VJP stores **no residuals** — the backward
 needs only the cotangent, unlike XLA's transpose which re-materialises
@@ -370,8 +369,8 @@ def interpolate(phi, tile, *, mode="separable", impl="jnp", dtype=None,
       tile: ``(dx, dy, dz)`` control-point spacing in voxels.
       mode: one of ``MODE_NAMES`` (``gather | matmul | separable | tt |
         ttli``).
-      impl: ``jnp`` (XLA-fused reference forms) or ``pallas`` (TPU kernels;
-        runs under ``interpret=True`` on CPU).
+      impl: ``jnp`` (XLA-fused reference forms) or ``pallas`` (TPU kernels
+        for ``separable`` / ``matmul``; the Pallas interpreter off-TPU).
       dtype: optional compute dtype (e.g. ``bfloat16``); the output takes
         this dtype, gradients stay in ``phi.dtype``.
       grad_impl: how this call differentiates (module docstring, "Gradient

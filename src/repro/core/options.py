@@ -143,6 +143,12 @@ class RegistrationOptions:
                      from equality/hash on purpose: it is introspection
                      metadata, not configuration, so it never fragments a
                      program cache.
+    skipped:         the BSI candidates the autotuner left out of its race,
+                     as ``(("mode/impl/grad_impl", reason), ...)`` — out of
+                     device memory at compile or load, or a compiled step
+                     too large to leave room for the rest of the
+                     registration.  Set by ``resolve_options``; introspection
+                     only, excluded from equality/hash like ``fused_reason``.
     """
 
     tile: tuple = (5, 5, 5)
@@ -161,6 +167,7 @@ class RegistrationOptions:
     fused: str = "auto"
     optimizer: Any = "adam"
     fused_reason: Any = dataclasses.field(default=None, compare=False)
+    skipped: tuple = dataclasses.field(default=(), compare=False)
 
     def __post_init__(self):
         tile = tuple(int(t) for t in self.tile)

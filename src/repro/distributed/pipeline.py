@@ -17,15 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-try:  # jax >= 0.6 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x/0.5.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# pvary only exists (and is only needed) on jax versions whose shard_map
-# tracks varying-axis state; older shard_map runs with check_rep=False.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 __all__ = ["pipeline_apply"]
 
 
@@ -54,9 +45,9 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, axis="pp", n_micro=None):
         stage = jax.lax.axis_index(axis)
         n_steps = n_micro + n_stages - 1
         # mark carries as axis-varying (they depend on the stage index)
-        buf = _pvary(jnp.zeros_like(micro[0]), (axis,))
-        outs = _pvary(jnp.zeros_like(micro), (axis,))
-        micro = _pvary(micro, (axis,))
+        buf = jax.lax.pvary(jnp.zeros_like(micro[0]), (axis,))
+        outs = jax.lax.pvary(jnp.zeros_like(micro), (axis,))
+        micro = jax.lax.pvary(micro, (axis,))
 
         def step(i, carry):
             buf, outs = carry
@@ -82,12 +73,7 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, axis="pp", n_micro=None):
             jnp.where(stage == n_stages - 1, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    kwargs = dict(mesh=mesh, in_specs=(PS(axis), PS()), out_specs=PS())
-    try:
-        # older shard_map's replication checker rejects the stage-varying
-        # carries that pvary would have annotated; disable it there.
-        shard = _shard_map(run, check_rep=False, **kwargs)
-    except TypeError:
-        shard = _shard_map(run, **kwargs)
+    shard = jax.shard_map(run, mesh=mesh, in_specs=(PS(axis), PS()),
+                          out_specs=PS())
     out = shard(stage_params, micro)
     return out.reshape(B, *x.shape[1:])

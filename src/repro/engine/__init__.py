@@ -30,7 +30,7 @@ from repro.engine.optimizer import (OPTIMIZERS, AdamOptimizer,
 from repro.engine.serve import (AsyncRegistrationService, QueueFull,
                                 RegistrationScheduler, RegistrationTimeout,
                                 ServeResult, ServeStats)
-from repro.engine.shard import make_registration_mesh, sharded_pipeline
+from repro.engine.shard import make_registration_mesh
 
 __all__ = [
     "BsiChoice",
@@ -67,5 +67,4 @@ __all__ = [
     "ServeResult",
     "ServeStats",
     "make_registration_mesh",
-    "sharded_pipeline",
 ]

@@ -69,6 +69,17 @@ class BsiChoice:
     # fused level step ("on" = core.ffd.fused_warp_loss won the race for
     # this configuration; entries written by autotune_fused only)
     fused: str = "off"
+    # candidates left out of the race, as ("mode/impl/grad_impl", reason)
+    skipped: tuple = ()
+
+
+# Share of the device's memory one candidate's compiled level step may take.
+# The registration keeps its volumes, pyramid and optimiser state beside the
+# step, and a program that only just fits alone fails to load: at phantom1 a
+# 14.6 GiB step compiled for a 15.75 GiB v5e and then found 14.4 GiB free.
+STEP_MEMORY_SHARE = 0.75
+NO_AUTODIFF = ("a Pallas forward has no autodiff rule; it runs only under "
+               "an analytic adjoint (grad_impl jnp, pallas or matmul)")
 
 
 _MEM_CACHE: dict = {}
@@ -140,7 +151,9 @@ def _parse_choice(hit):
         choice = BsiChoice(str(hit["mode"]), str(hit["impl"]),
                            float(hit["us_per_call"]),
                            str(hit.get("grad_impl", "xla")),
-                           str(hit.get("fused", "off")))
+                           str(hit.get("fused", "off")),
+                           tuple((str(c), str(r))
+                                 for c, r in hit.get("skipped", ())))
     except (KeyError, TypeError, ValueError, AttributeError):
         return None
     return choice if choice.fused in ("on", "off") else None
@@ -158,6 +171,62 @@ def _store_disk(path, key, choice) -> None:
         os.replace(tmp, path)  # atomic: concurrent tuners never corrupt it
     except OSError:
         pass  # cache is best-effort; tuning still returned in-process
+
+
+def _is_out_of_memory(err) -> bool:
+    """Whether ``err`` is XLA's out-of-memory error (device HBM or a
+    kernel's VMEM), the one failure a candidate may be skipped for."""
+    return (isinstance(err, jax.errors.JaxRuntimeError)
+            and str(err).startswith("RESOURCE_EXHAUSTED"))
+
+
+def _device_bytes_limit(dev):
+    stats = dev.memory_stats() if hasattr(dev, "memory_stats") else None
+    return (stats or {}).get("bytes_limit")
+
+
+def _step_footprint(compiled) -> int:
+    """Device bytes a compiled program holds while it runs."""
+    ma = compiled.memory_analysis()
+    return int(ma.temp_size_in_bytes + ma.argument_size_in_bytes
+               + ma.output_size_in_bytes - ma.alias_size_in_bytes
+               + ma.generated_code_size_in_bytes)
+
+
+def _time_candidates(cands, args, dev, reps):
+    """Compile, admit and time each ``(name, jitted_fn)``.
+
+    Returns ``(timed, skipped)``: ``[(us, name)]`` and ``[(name, reason)]``.
+    Only an out-of-memory error (at compile or at load) skips a candidate,
+    as does a compiled step that would leave too little of the device for
+    the rest of the registration (:data:`STEP_MEMORY_SHARE`); every other
+    exception propagates.
+    """
+    limit = _device_bytes_limit(dev)
+    timed, skipped = [], []
+    for name, fn in cands:
+        try:
+            compiled = fn.lower(*args).compile()
+            need = _step_footprint(compiled)
+            if limit and need > STEP_MEMORY_SHARE * limit:
+                skipped.append((name, (
+                    f"needs {need / 2**30:.2f} GiB of the device's "
+                    f"{limit / 2**30:.2f} GiB; a level step may take "
+                    f"{STEP_MEMORY_SHARE:.0%}")))
+                continue
+            jax.block_until_ready(compiled(*args))  # load + warm up
+        except jax.errors.JaxRuntimeError as e:
+            if not _is_out_of_memory(e):
+                raise
+            skipped.append((name, str(e).splitlines()[0][:300]))
+            continue
+        times = []
+        for _ in range(max(1, reps)):
+            t0 = time.perf_counter()
+            jax.block_until_ready(compiled(*args))
+            times.append(time.perf_counter() - t0)
+        timed.append((float(np.median(times) * 1e6), name))
+    return timed, skipped
 
 
 def autotune_bsi(grid_shape, tile, channels=3, *, candidates=None, reps=3,
@@ -270,12 +339,15 @@ def autotune_bsi(grid_shape, tile, channels=3, *, candidates=None, reps=3,
     # is pure data parallelism — each device runs the whole per-pair loop —
     # so the single-device measurement *is* the per-shard workload, and
     # pinning keeps the timing stable when the process holds a pod (or
-    # XLA_FLAGS-faked multi-device) context.
+    # XLA_FLAGS-faked multi-device) context.  The volumes are arguments of
+    # the timed programs, never captured constants, so the programs stay
+    # small enough for the persistent compilation cache.
     dev = jax.local_devices()[0]
     rng = np.random.default_rng(0)
     phi = jax.device_put(
         jnp.asarray(rng.standard_normal(grid_shape + (channels,)),
                     jnp.float32), dev)
+    args = (phi,)
     objective = None
     if measure_grad and similarity is not None:
         _, sim_fn = resolve_similarity(similarity)
@@ -289,48 +361,50 @@ def autotune_bsi(grid_shape, tile, channels=3, *, candidates=None, reps=3,
 
             mov = jax.device_put(jnp.asarray(rng.random(dense_shape),
                                              jnp.float32), dev)
+            args = (phi, mov, fix)
 
-            def objective(out):
+            def objective(out, mov, fix):
                 if velocity:
                     out = scaling_and_squaring(out, tspec.squarings)
                 warped = warp_volume(mov, out, compute_dtype=compute_dtype)
                 return sim_fn(warped.astype(fix.dtype), fix)
         else:
+            args = (phi, fix)
 
-            def objective(out):
+            def objective(out, fix):
                 return sim_fn(out[..., 0].astype(fix.dtype), fix)
 
-    best = None
+    jobs, skipped = [], []
     for cand in cands:
         mode, impl = cand[0], cand[1]
         gi = cand[2] if len(cand) == 3 else "xla"
+        name = "/".join(cand)
+        if measure_grad and impl == "pallas" and gi == "xla":
+            skipped.append((name, NO_AUTODIFF))
+            continue
 
         def fwd(p, mode=mode, impl=impl, gi=gi):
             return interpolate(p, tile, mode=mode, impl=impl, grad_impl=gi,
                                dtype=compute_dtype)
 
         if measure_grad and objective is not None:
-            fn = jax.jit(jax.grad(lambda p: objective(fwd(p))))
+            fn = jax.jit(jax.grad(
+                lambda p, *data, fwd=fwd: objective(fwd(p), *data)))
         elif measure_grad:
-            fn = jax.jit(jax.grad(lambda p: fwd(p).sum()))
+            fn = jax.jit(jax.grad(lambda p, fwd=fwd: fwd(p).sum()))
         else:
             fn = jax.jit(fwd)  # consumers always run the form under jit
-        try:
-            jax.block_until_ready(fn(phi))  # compile + warmup
-        except Exception:
-            continue  # candidate unavailable on this backend/workload
-        times = []
-        for _ in range(max(1, reps)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(phi))
-            times.append(time.perf_counter() - t0)
-        us = float(np.median(times) * 1e6)
-        if best is None or us < best.us_per_call:
-            best = BsiChoice(mode, impl, us, gi)
-    if best is None:
+        jobs.append((name, fn))
+    timed, oom = _time_candidates(jobs, args, dev, reps)
+    skipped = tuple(skipped + oom)
+    if not timed:
         raise RuntimeError(
-            f"no BSI candidate succeeded for grid={grid_shape} tile={tile} "
-            f"candidates={cands}")
+            f"no BSI candidate fits for grid={grid_shape} tile={tile}; "
+            f"skipped: {skipped}")
+    us, name = min(timed)
+    win = name.split("/")
+    best = BsiChoice(win[0], win[1], us, win[2] if len(win) == 3 else "xla",
+                     skipped=skipped)
 
     if use_cache:
         _MEM_CACHE[mem_key] = best
@@ -399,36 +473,26 @@ def autotune_fused(grid_shape, tile, vol_shape, *, base, similarity,
     mov = jax.device_put(jnp.asarray(rng.random(vol_shape), jnp.float32), dev)
     fix = jax.device_put(jnp.asarray(rng.random(vol_shape), jnp.float32), dev)
 
-    def unfused_loss(p):
+    def unfused_loss(p, mov, fix):
         disp = ffd.dense_field(p, tile, vol_shape, mode=base.mode,
                                impl=base.impl, grad_impl=base.grad_impl,
                                compute_dtype=compute_dtype)
         warped = ffd.warp_volume(mov, disp, compute_dtype=compute_dtype)
         return sim_fn(warped.astype(jnp.float32), fix)
 
-    def fused_loss(p):
+    def fused_loss(p, mov, fix):
         return ffd.fused_warp_loss(p, mov, fix, tile, similarity=similarity,
                                    mode=base.mode, impl=base.impl,
                                    grad_impl=base.grad_impl,
                                    compute_dtype=compute_dtype)
 
-    best = dataclasses.replace(base, fused="off")
-    timed = []
-    for flag, loss in (("off", unfused_loss), ("on", fused_loss)):
-        fn = jax.jit(jax.grad(loss))
-        try:
-            jax.block_until_ready(fn(phi))  # compile + warmup
-        except Exception:
-            continue  # candidate unavailable on this backend/workload
-        times = []
-        for _ in range(max(1, reps)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(phi))
-            times.append(time.perf_counter() - t0)
-        timed.append((float(np.median(times) * 1e6), flag))
+    timed, skipped = _time_candidates(
+        [("off", jax.jit(jax.grad(unfused_loss))),
+         ("on", jax.jit(jax.grad(fused_loss)))], (phi, mov, fix), dev, reps)
+    best = dataclasses.replace(base, fused="off", skipped=tuple(skipped))
     if timed:
         us, flag = min(timed)
-        best = dataclasses.replace(base, fused=flag, us_per_call=us)
+        best = dataclasses.replace(best, fused=flag, us_per_call=us)
     if use_cache:
         _MEM_CACHE[mem_key] = best
         _store_disk(cache_path, key, best)
@@ -464,25 +528,32 @@ def resolve_bsi(mode, impl, grid_shape, tile, channels=3, *, grad_impl=None,
     ``"auto"``, tunes the joint forward+adjoint workload (``measure_grad``
     is implied: the adjoint axis only exists in the backward).
     """
+    return _resolve(mode, impl, grid_shape, tile, channels,
+                    grad_impl=grad_impl, **tune_kwargs)[0]
+
+
+def _resolve(mode, impl, grid_shape, tile, channels=3, *, grad_impl=None,
+             **tune_kwargs):
+    """:func:`resolve_bsi` plus the candidates the race skipped."""
     if grad_impl is None:
         if mode != "auto" and impl != "auto":
-            return mode, impl
+            return (mode, impl), ()
         cands = _candidate_pool(mode, impl)
         if not cands:
             raise ValueError(
                 f"no BSI candidates match mode={mode!r} impl={impl!r}")
         if len(cands) == 1:
-            return cands[0]
+            return cands[0], ()
         choice = autotune_bsi(grid_shape, tile, channels,
                               candidates=cands, **tune_kwargs)
-        return choice.mode, choice.impl
+        return (choice.mode, choice.impl), choice.skipped
 
     if grad_impl != "auto" and grad_impl not in GRAD_IMPLS:
         raise ValueError(
             f"unknown grad_impl {grad_impl!r}; choose from {GRAD_IMPLS}"
             " or 'auto'")
     if mode != "auto" and impl != "auto" and grad_impl != "auto":
-        return mode, impl, grad_impl
+        return (mode, impl, grad_impl), ()
     gis = default_grad_impls() if grad_impl == "auto" else (grad_impl,)
     if grad_impl == "auto" and tune_kwargs.get("compute_dtype") is not None:
         # plain autodiff of a reduced-precision forward accumulates the
@@ -490,17 +561,18 @@ def resolve_bsi(mode, impl, grid_shape, tile, channels=3, *, grad_impl=None,
         # documented fp32 accumulation, so "auto" never picks "xla" here
         # (an *explicit* grad_impl="xla" still passes through above)
         gis = tuple(g for g in gis if g != "xla") or gis
+    # a Pallas forward differentiates only through an analytic adjoint
     cands = tuple(c + (gi,) for c in _candidate_pool(mode, impl)
-                  for gi in gis)
+                  for gi in gis if not (c[1] == "pallas" and gi == "xla"))
     if not cands:
         raise ValueError(f"no BSI candidates match mode={mode!r} "
                          f"impl={impl!r} grad_impl={grad_impl!r}")
     if len(cands) == 1:
-        return cands[0]
+        return cands[0], ()
     tune_kwargs["measure_grad"] = True
     choice = autotune_bsi(grid_shape, tile, channels,
                           candidates=cands, **tune_kwargs)
-    return choice.mode, choice.impl, choice.grad_impl
+    return (choice.mode, choice.impl, choice.grad_impl), choice.skipped
 
 
 @functools.lru_cache(maxsize=256)
@@ -535,7 +607,7 @@ def resolve_options(options, vol_shape):
     opts = options.normalized()
     vol_shape = tuple(int(s) for s in vol_shape)
     grid_shape = ffd.grid_shape_for_volume(vol_shape, opts.tile)
-    mode, impl, grad_impl = resolve_bsi(
+    (mode, impl, grad_impl), skipped = _resolve(
         opts.mode, opts.impl, grid_shape, opts.tile,
         grad_impl=opts.grad_impl,  # the adjoint axis is tuned jointly
         measure_grad=True,  # the loop's workload is forward+backward BSI
@@ -543,7 +615,8 @@ def resolve_options(options, vol_shape):
         compute_dtype=opts.compute_dtype,  # ... measured/cached per dtype
         transform=opts.transform,  # ... velocity integrates before the warp
         optimizer=opts.optimizer)  # ... non-default optimisers key apart
-    opts = opts.replace(mode=mode, impl=impl, grad_impl=grad_impl)
+    opts = opts.replace(mode=mode, impl=impl, grad_impl=grad_impl,
+                        skipped=skipped)
     is_velocity = isinstance(opts.transform, VelocityTransform)
     from repro.engine.optimizer import GaussNewtonOptimizer
 

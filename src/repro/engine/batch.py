@@ -248,17 +248,6 @@ def _compiled_batch(vol_shape, options, mesh=None):
     fixed-length scan program differ through ``options.stop``."""
     del vol_shape  # cache key only; jax re-traces on new shapes anyway
     o = options
-    if mesh is not None:
-        from repro.engine.shard import compile_sharded_batch
-
-        return compile_sharded_batch(mesh, o.tile, o.levels, o.iters, o.lr,
-                                     o.bending_weight, o.mode, o.impl,
-                                     o.similarity, grad_impl=o.grad_impl,
-                                     compute_dtype=o.compute_dtype,
-                                     transform=o.transform,
-                                     regularizer=o.regularizer,
-                                     stop=o.stop, fused=o.fused,
-                                     optimizer=o.optimizer)
 
     def single(f, m):
         return ffd_pipeline(f, m, tile=o.tile, levels=o.levels,
@@ -270,7 +259,11 @@ def _compiled_batch(vol_shape, options, mesh=None):
                             regularizer=o.regularizer, stop=o.stop,
                             fused=o.fused, optimizer=o.optimizer)
 
-    return jax.jit(jax.vmap(single))
+    if mesh is None:
+        return jax.jit(jax.vmap(single))
+    from repro.engine.shard import compile_sharded_batch
+
+    return compile_sharded_batch(jax.vmap(single), mesh)
 
 
 def register_batch(fixed, moving, *, options=None, tile=UNSET, levels=UNSET,
@@ -305,10 +298,11 @@ def register_batch(fixed, moving, *, options=None, tile=UNSET, levels=UNSET,
         ``similarity="ssd"``.
       mesh: optional ``jax.sharding.Mesh`` (see
         ``engine.shard.make_registration_mesh``) — the batch axis shards
-        over the mesh's data axes (``REGISTRATION_RULES``), one program
-        serving all devices.  Non-divisible batches are padded (repeating
-        the last pair) and stripped on return, so results are identical to
-        the unsharded path for any B.  Deliberately *not* an options field:
+        over the mesh's data axes (``REGISTRATION_RULES``) and every device
+        runs the one-device program on its rows (``shard_map``).
+        Non-divisible batches are padded (repeating the last pair) and
+        stripped on return, so results are identical to the unsharded path
+        for any B.  Deliberately *not* an options field:
         it names physical devices, so it would poison option-keyed caches.
       stop: optional ``ConvergenceConfig`` — run each pyramid level as an
         early-stopped ``lax.while_loop`` instead of a fixed-``iters`` scan

@@ -376,9 +376,11 @@ class RegistrationScheduler:
         W = self.lanes
         gshape = ffd.grid_shape_for_volume(lvl_shape, bucket.options.tile)
         grid = gshape + (3,)
-        zg = jnp.zeros((W,) + grid, jnp.float32)
-        zi = jnp.zeros((W,), jnp.int32)
-        zf = jnp.zeros((W,), jnp.float32)
+        # a fresh buffer per leaf: the splice and chunk programs donate the
+        # state, and one buffer behind two leaves cannot be donated twice
+        zg = functools.partial(jnp.zeros, (W,) + grid, jnp.float32)
+        zi = functools.partial(jnp.zeros, (W,), jnp.int32)
+        zf = functools.partial(jnp.zeros, (W,), jnp.float32)
         # the optimiser state's lane template comes from the registry, so a
         # new optimiser's lanes allocate (and shard) without touching the
         # scheduler: every leaf is stacked to a leading (W, ...) lane axis
@@ -386,8 +388,9 @@ class RegistrationScheduler:
             lambda a: jnp.zeros((W,) + a.shape, a.dtype),
             init_state(bucket.options.optimizer, jnp.zeros(grid,
                                                            jnp.float32)))
-        state = dict(phi=zg, opt=opt, g=zg, best_p=zg, k=zi, since=zi,
-                     best=zf, loss=zf, active=jnp.zeros((W,), jnp.bool_))
+        state = dict(phi=zg(), opt=opt, g=zg(), best_p=zg(), k=zi(),
+                     since=zi(), best=zf(), loss=zf(),
+                     active=jnp.zeros((W,), jnp.bool_))
         stage.fixed = jnp.zeros((W,) + lvl_shape, jnp.float32)
         stage.moving = jnp.zeros((W,) + lvl_shape, jnp.float32)
         stage.lanes = [None] * W

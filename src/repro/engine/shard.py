@@ -1,27 +1,27 @@
 """Mesh-sharded batched registration — data-parallel serving over a pod.
 
 ``engine.batch.register_batch`` compiles one ``jit(vmap)`` program pinned to
-a single device; this module places that program's batch axis over a
-``jax.sharding.Mesh`` instead, so a pod of N accelerators serves N shards of
-a registration batch concurrently (Budelmann et al. and Brunn et al. — see
-PAPERS.md — both get intra-operative latencies from scaling the *loop*
-across devices, not just the kernel).
+a single device; this module runs that same program on every device of a
+``jax.sharding.Mesh`` instead, each device on its own shard of the batch,
+so a pod of N accelerators serves N shards of a registration batch
+concurrently (Budelmann et al. and Brunn et al. — see PAPERS.md — both get
+intra-operative latencies from scaling the *loop* across devices, not just
+the kernel).
 
-The layout comes from ``repro.distributed.sharding.REGISTRATION_RULES``:
-batch → the mesh's data axes, everything per-pair (volume and grid geometry,
-the displacement channel, optimiser state, loss traces) replicated per
-shard.
-``sharded_pipeline`` re-states that placement with
-``with_sharding_constraint`` at every pyramid level and ``lax.scan``
-boundary, so GSPMD never has a reason to gather the batch axis mid-loop.
+The program runs under ``shard_map`` over the batch axes of
+``repro.distributed.sharding.REGISTRATION_RULES``: every per-pair array is
+local to its device, so no collective is needed, GSPMD never has to
+partition the loop, and the Pallas kernels (which Mosaic cannot partition
+automatically) run as ordinary per-device calls.  The per-device program is
+the one-device program at batch ``B / n``.
 
 Non-divisible batches are padded (repeating the last pair) up to the batch
 multiple of the mesh; ``register_batch`` strips the pad rows on return.
-Callers driving ``compile_sharded_batch`` / ``sharded_pipeline`` directly
-get the *padded* outputs and can mask the synthetic rows with
-``batch_mask``.  ``make_registration_mesh()`` works on real accelerators and
-on fake CPU devices (``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-exported before jax is imported), which is how CI exercises this path.
+Callers driving ``compile_sharded_batch`` directly get the *padded* outputs
+and can mask the synthetic rows with ``batch_mask``.
+``make_registration_mesh()`` works on real accelerators and on fake CPU
+devices (``XLA_FLAGS=--xla_force_host_platform_device_count=8`` exported
+before jax is imported), which is how CI exercises this path.
 """
 from __future__ import annotations
 
@@ -29,11 +29,9 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 
-from repro.core import ffd
 from repro.distributed.sharding import REGISTRATION_RULES
-from repro.engine.loop import optimize_scan
 
 __all__ = [
     "VOLUME_AXES",
@@ -44,7 +42,6 @@ __all__ = [
     "pad_batch",
     "batch_mask",
     "lane_sharding",
-    "sharded_pipeline",
     "compile_sharded_batch",
 ]
 
@@ -71,7 +68,8 @@ def make_registration_mesh(num_devices=None, *, devices=None):
             f"need {n} devices for a registration mesh, have {len(devs)}; "
             "on CPU export XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{max(n, 2)} before importing jax to fake a pod")
-    return jax.make_mesh((n,), ("data",), devices=devs[:n])
+    return jax.make_mesh((n,), ("data",), devices=devs[:n],
+                         axis_types=(AxisType.Auto,))
 
 
 def batch_multiple(mesh) -> int:
@@ -107,7 +105,7 @@ def batch_mask(orig_b, padded_b):
     """Boolean ``(padded_b,)`` mask: True for real rows, False for padding.
 
     ``register_batch`` strips pad rows itself; this is for callers that use
-    ``compile_sharded_batch``/``sharded_pipeline`` directly and therefore
+    ``compile_sharded_batch`` directly and therefore
     hold padded outputs (e.g. to exclude synthetic rows from aggregate
     loss/quality statistics without a host round-trip).
     """
@@ -130,125 +128,18 @@ def lane_sharding(mesh):
         ("batch",)))
 
 
-def sharded_pipeline(fixed, moving, *, tile, levels, iters, lr,
-                     bending_weight, mode, impl, similarity, mesh,
-                     grad_impl="xla", compute_dtype=None,
-                     transform="displacement", regularizer="none",
-                     rules=None, stop=None, fused="off", optimizer="adam"):
-    """Batched multi-level FFD with explicit sharding constraints.
+def compile_sharded_batch(batched, mesh):
+    """``jit(shard_map(batched))`` over the mesh's batch axes.
 
-    Same math as ``jax.vmap(engine.batch.ffd_pipeline)`` — the pyramid, the
-    per-level ``ffd_level_objective`` + ``optimize_scan``, the final warp —
-    but batch-first, with the REGISTRATION_RULES placement re-asserted on
-    the pyramid, on the control grid entering and leaving every scan level,
-    and on the outputs.  Returns ``(warped, phi, losses)`` with shapes
-    ``(B, X, Y, Z)``, ``(B, *grid, 3)``, ``(B, levels)``.
-
-    ``optimizer`` (name or ``engine.optimizer`` spec) picks the per-level
-    loop; every registered step is pure per-pair arithmetic — bounded inner
-    loops, validity masks, no data-dependent shapes — so the L-BFGS history
-    window and the Gauss-Newton CG solve shard exactly like the Adam
-    moments (per-pair state replicated along the batch axis, no cross-
-    device traffic beyond the loop predicate's all-reduce).
-
-    ``stop`` (a resolved ``ConvergenceConfig``) swaps each level's scan for
-    the early-stopped ``lax.while_loop``
-    (``engine.convergence.optimize_until``) — the loop's lane masking is
-    per-pair arithmetic too, so it shards exactly like the scan — and
-    appends a ``(B, levels)`` steps array to the return.
+    ``batched`` is the one-device batch program, ``(F, M) -> outputs`` with
+    every input and output batch-leading (``engine.batch._compiled_batch``
+    builds it as ``vmap`` of the per-pair pipeline).  Each device runs it on
+    its ``B / n`` rows; ``in_shardings`` place the incoming stacks batch-
+    over-data (uncommitted host arrays are transferred shard-by-shard, never
+    materialised whole on one device) and the outputs stay distributed.
+    The batch must be a multiple of :func:`batch_multiple` (``pad_batch``).
     """
-    from repro.engine.batch import ffd_level_objective
-    from repro.engine.convergence import optimize_until
-
-    rules = REGISTRATION_RULES(mesh.axis_names) if rules is None else rules
-
-    def cons(x, axes):
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, rules.spec(axes)))
-
-    pyramid = [(fixed, moving)]
-    for _ in range(levels - 1):
-        f, m = pyramid[-1]
-        pyramid.append((jax.vmap(ffd.downsample2)(f),
-                        jax.vmap(ffd.downsample2)(m)))
-    pyramid = [(cons(f, VOLUME_AXES), cons(m, VOLUME_AXES))
-               for f, m in pyramid[::-1]]  # coarse -> fine
-
-    phi = None
-    finals = []
-    steps = []
-    for f, m in pyramid:
-        gshape = ffd.grid_shape_for_volume(f.shape[1:], tile)
-        if phi is None:
-            phi = jnp.zeros((f.shape[0],) + gshape + (3,), jnp.float32)
-        else:
-            phi = jax.vmap(lambda p, g=gshape: ffd.upsample_grid(p, g))(phi)
-        phi = cons(phi, GRID_AXES)
-
-        def level(f1, m1, p1):
-            obj = ffd_level_objective(
-                f1, m1, tile=tile, bending_weight=bending_weight,
-                mode=mode, impl=impl, grad_impl=grad_impl,
-                compute_dtype=compute_dtype, similarity=similarity,
-                transform=transform, regularizer=regularizer,
-                fused=fused)
-            if stop is None:
-                return optimize_scan(obj, p1, optimizer=optimizer,
-                                     iters=iters, lr=lr)
-            return optimize_until(obj, p1, optimizer=optimizer, stop=stop,
-                                  lr=lr)
-
-        out = jax.vmap(level)(f, m, phi)
-        phi, trace = out[:2]
-        if stop is not None:
-            steps.append(out[2])
-        phi = cons(phi, GRID_AXES)
-        finals.append(trace[:, -1])
-
-    def finish(m1, p1):
-        from repro.core.transform import dense_displacement
-
-        disp = dense_displacement(transform, p1, tile, m1.shape, mode=mode,
-                                  impl=impl, grad_impl=grad_impl)
-        return ffd.warp_volume(m1, disp)
-
-    warped = cons(jax.vmap(finish)(moving, phi), VOLUME_AXES)
-    losses = cons(jnp.stack(finals, axis=1), LOSS_AXES)
-    if stop is None:
-        return warped, phi, losses
-    return warped, phi, losses, cons(jnp.stack(steps, axis=1), LOSS_AXES)
-
-
-def compile_sharded_batch(mesh, tile, levels, iters, lr,
-                          bending_weight, mode, impl, similarity,
-                          grad_impl="xla", compute_dtype=None,
-                          transform="displacement", regularizer="none",
-                          stop=None, fused="off", optimizer="adam"):
-    """Build the jitted sharded pipeline for one (mesh, configuration).
-
-    Uncached by design: ``engine.batch._compiled_batch`` is the single
-    program cache (its key includes ``mesh`` — ``jax.sharding.Mesh`` hashes
-    by devices + axis names, so two meshes over the same pod share a compile
-    and a re-deployed mesh gets its own).  ``in_shardings`` place the
-    incoming stacks batch-over-data (uncommitted host arrays are transferred
-    shard-by-shard, never materialised whole on one device);
-    ``out_shardings`` keep results distributed for the caller.
-    """
-    rules = REGISTRATION_RULES(mesh.axis_names)
-    vol_sh = NamedSharding(mesh, rules.spec(VOLUME_AXES))
-    loss_sh = NamedSharding(mesh, rules.spec(LOSS_AXES))
-    out_sh = (vol_sh, NamedSharding(mesh, rules.spec(GRID_AXES)), loss_sh)
-    if stop is not None:  # the (B, levels) steps array shards like losses
-        out_sh = out_sh + (loss_sh,)
-
-    def batched(F, M):
-        return sharded_pipeline(
-            F, M, tile=tile, levels=levels, iters=iters, lr=lr,
-            bending_weight=bending_weight, mode=mode, impl=impl,
-            grad_impl=grad_impl, compute_dtype=compute_dtype,
-            similarity=similarity, transform=transform,
-            regularizer=regularizer, mesh=mesh, rules=rules, stop=stop,
-            fused=fused, optimizer=optimizer)
-
-    return jax.jit(batched, in_shardings=(vol_sh, vol_sh),
-                   out_shardings=out_sh)
+    sh = lane_sharding(mesh)
+    body = jax.shard_map(batched, mesh=mesh, in_specs=(sh.spec, sh.spec),
+                         out_specs=sh.spec, check_vma=False)
+    return jax.jit(body, in_shardings=(sh, sh), out_shardings=sh)

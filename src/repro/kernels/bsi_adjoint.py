@@ -1,35 +1,24 @@
-"""Gather-based BSI adjoint Pallas kernel — thread-per-control-point.
+"""BSI adjoint Pallas kernels: dense cotangent -> control-grid cotangent.
 
-The adjoint mirror of the forward kernels' Thread-per-Tile scheme: where the
-forward broadcasts a VMEM-resident control grid over blocks of voxels, the
-backward reduces a VMEM-resident voxel cotangent over blocks of *control
-points*.  XLA's transpose of the gather/tt/ttli forwards is a per-voxel
-scatter-add into the control grid — the maximal-data-movement pattern the
-paper's §3 design exists to avoid; this kernel replaces it with the
-separable-transpose contraction (``core.interpolate.bsi_adjoint_separable``)
-run per control-point block:
+BSI is linear, so its gradient is the transpose of the forward map.  Both
+kernels run in the plane layout of ``kernels.common`` and are the exact
+transposes of the two forward kernels, so every in-kernel op stays a 2-D
+matmul or an elementwise FMA:
 
-* the dense cotangent is zero-padded by 3 tiles per axis (``ops.py``), so
-  every control point uniformly owns the padded-tile window ``[i, i+4)`` —
-  the exact mirror of the forward's ``(bt+3)^3`` halo window and the same
-  Eq. (A.4) overlap saving, now on the gradient;
-* each Pallas grid cell reduces its ``((bc+3)*d)^3`` cotangent window to a
-  ``bc^3`` block of control-point gradients with three per-axis
-  ``dot_general`` sweeps (MXU-friendly) + 4-band overlap-adds, accumulated
-  in fp32 on-chip;
-* the control-grid gradient (the small array) is written exactly once.
+``separable``  (``grad_impl="pallas"``) transposes ``bsi_separable``: the x
+               taps fold each tile's ``dx`` cotangent planes onto a VMEM
+               window of ``bt + 3`` dense planes (VPU), then each window
+               plane projects to control space as ``Ay^T @ G @ Az`` (MXU).
+``matmul``     (``grad_impl="matmul"``) transposes ``bsi_matmul``: every
+               cotangent plane projects first (``Ay^T @ g_x @ Az``), and the
+               x taps then act on the small control planes.
 
-Two forms share that window/padding scheme (``ops.bsi_adjoint_pallas``
-dispatches via ``form=``):
-
-``separable``  the three per-axis sweep contraction above
-               (``grad_impl="pallas"``);
-``matmul``     the transposed matrix form (``grad_impl="matmul"``): the
-               window's per-tile ``d^3`` cotangents contract against the
-               ``(d^3, 64)`` Kronecker basis in one MXU-shaped
-               ``dot_general`` — ``c4[k, t] = sum_v B[v, k] * g[t, v]``,
-               the exact transpose of ``bsi_matmul``'s forward product —
-               followed by the same shifted overlap-adds.
+Each grid cell reads its ``bt * dx`` cotangent planes once.  The control
+grid of one channel stays resident in VMEM as the output block across the
+sequential x axis of the grid and accumulates in fp32 (bf16 cotangents
+included): neighbouring cells share three control planes, the transpose of
+the forward's halo overlap, and accumulating is what makes that sharing a
+reduction instead of a scatter.
 """
 from __future__ import annotations
 
@@ -38,169 +27,90 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 
-__all__ = ["bsi_adjoint_separable_pallas", "bsi_adjoint_matmul_pallas"]
+__all__ = ["bsi_adjoint_pallas_planes"]
+
+def _project(ayt, az, plane):
+    return common.mxu_dot(ayt, common.mxu_dot(plane, az))
 
 
-def _band_sum(c4, b):
-    """Overlap-add the four shifted bands: out[j] = sum_l c4[l, j + 3 - l].
+def _kernel_separable(ayt_ref, az_ref, g_ref, out_ref, q_ref, *, dx, bt, taps):
+    i = pl.program_id(1)
 
-    ``c4``: ``(4, bc+3, R)`` per-band contractions over padded tiles;
-    returns ``(bc, R)``.  Band ``l`` contributes tile ``j + 3 - l`` to
-    control point ``j`` — the transpose of the forward's ``phi[t + l]`` read.
+    @pl.when(i == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    q_ref[...] = jnp.zeros_like(q_ref)
+
+    def tile(j, carry):
+        rows = [g_ref[0, j * dx + a].astype(jnp.float32) for a in range(dx)]
+        for l in range(4):
+            q_ref[j + l] += sum(w[l] * r for w, r in zip(taps, rows))
+        return carry
+
+    jax.lax.fori_loop(0, bt, tile, 0)
+    ayt = ayt_ref[...]
+    az = az_ref[...]
+    t0 = i * bt
+
+    def project(j, carry):
+        out_ref[0, t0 + j] += _project(ayt, az, q_ref[j])
+        return carry
+
+    jax.lax.fori_loop(0, bt + 3, project, 0)
+
+
+def _kernel_matmul(ayt_ref, az_ref, g_ref, out_ref, *, dx, bt, taps):
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    ayt = ayt_ref[...]
+    az = az_ref[...]
+    t0 = i * bt
+
+    def tile(j, carry):
+        d = [_project(ayt, az, g_ref[0, j * dx + a].astype(jnp.float32))
+             for a in range(dx)]
+        for l in range(4):
+            out_ref[0, t0 + j + l] += sum(w[l] * r for w, r in zip(taps, d))
+        return carry
+
+    jax.lax.fori_loop(0, bt, tile, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("dx", "bt", "form", "interpret"))
+def bsi_adjoint_pallas_planes(g, ayt, az, *, dx, bt, form, interpret):
+    """Planes-layout adjoint: ``g (C, nb*bt*dx, Y, Z)`` -> ``(C, nb*bt+3, Ny, Nz)``.
+
+    ``ayt`` is the ``(Ny, Y)`` transposed y band matrix and ``az`` the
+    ``(Z, Nz)`` z band matrix, both float32; the output is float32.
     """
-    return sum(c4[l, 3 - l : 3 - l + b] for l in range(4))
-
-
-def _kernel(wx_ref, wy_ref, wz_ref, g_ref, out_ref, *, tile, block_ctrl):
-    dx, dy, dz = tile
-    bx, by, bz = block_ctrl
-    c = out_ref.shape[-1]
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    k = pl.program_id(2)
-    # This cell's cotangent window: padded tiles [i0, i0 + bc + 3) per axis.
-    win = g_ref[
-        pl.ds(i * bx * dx, (bx + 3) * dx),
-        pl.ds(j * by * dy, (by + 3) * dy),
-        pl.ds(k * bz * dz, (bz + 3) * dz),
-        :,
-    ].astype(jnp.float32)  # fp32 on-chip accumulation for bf16 cotangents
-    wx = wx_ref[...].astype(jnp.float32)
-    wy = wy_ref[...].astype(jnp.float32)
-    wz = wz_ref[...].astype(jnp.float32)
-    X, Y = (bx + 3) * dx, (by + 3) * dy
-
-    # z sweep: contract the in-tile voxel axis against the LUT, then
-    # overlap-add -> (X, Y, bz, C).  Reverse axis order (z, y, x) so the
-    # intermediates shrink as early as possible.
-    u = win.reshape(X * Y, bz + 3, dz, c)
-    c4 = jax.lax.dot_general(
-        wz, u, (((0,), (2,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (4, X*Y, bz+3, C)
-    h = _band_sum(jnp.moveaxis(c4, 1, 3).reshape(4, bz + 3, c * X * Y), bz)
-    h = h.reshape(bz, c, X, Y)
-    # y sweep -> (X, by, bz, C) laid out as (by, bz*C*X)
-    u = h.reshape(bz * c * X, by + 3, dy).transpose(1, 2, 0)
-    c4 = jax.lax.dot_general(
-        wy, u, (((0,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (4, by+3, bz*C*X)
-    h = _band_sum(c4, by).reshape(by, bz, c, X)
-    # x sweep -> (bx, by, bz, C)
-    u = h.reshape(by * bz * c, bx + 3, dx).transpose(1, 2, 0)
-    c4 = jax.lax.dot_general(
-        wx, u, (((0,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (4, bx+3, by*bz*C)
-    h = _band_sum(c4, bx).reshape(bx, by, bz, c)
-    out_ref[...] = h.astype(out_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("tile", "block_ctrl", "out_dtype", "interpret")
-)
-def bsi_adjoint_separable_pallas(gp, wx, wy, wz, *, tile, block_ctrl,
-                                 out_dtype=jnp.float32, interpret=True):
-    """Padded dense cotangent -> control-grid cotangent, blocked.
-
-    Args:
-      gp: ``((Nx+3)*dx, (Ny+3)*dy, (Nz+3)*dz, C)`` cotangent zero-padded by
-        3 tiles per axis (``ops.bsi_adjoint_pallas`` pads), where ``N*`` is
-        the stored control count, padded up to a ``block_ctrl`` multiple.
-      wx, wy, wz: ``(d, 4)`` aligned-grid weight LUTs.
-      tile: ``(dx, dy, dz)`` spacing; ``block_ctrl``: control points per
-        Pallas grid cell (must divide ``N*``).
-
-    Returns:
-      ``(Nx, Ny, Nz, C)`` control-grid cotangent in ``out_dtype``.
-    """
-    dx, dy, dz = tile
-    c = gp.shape[3]
-    nx, ny, nz = (s // d - 3 for s, d in zip(gp.shape[:3], tile))
-    bx, by, bz = block_ctrl
-    assert nx % bx == 0 and ny % by == 0 and nz % bz == 0, (gp.shape, block_ctrl)
-    grid = (nx // bx, ny // by, nz // bz)
-    out_shape = jax.ShapeDtypeStruct((nx, ny, nz, c), out_dtype)
+    c, xp, y, z = g.shape
+    ny, nz = ayt.shape[0], az.shape[1]
+    nb = xp // (bt * dx)
+    assert nb * bt * dx == xp, (g.shape, dx, bt)
+    nxp = nb * bt + 3
+    kernel = _kernel_separable if form == "separable" else _kernel_matmul
+    scratch = ([pltpu.VMEM((bt + 3, y, z), jnp.float32)]
+               if form == "separable" else [])
     return pl.pallas_call(
-        functools.partial(_kernel, tile=tile, block_ctrl=block_ctrl),
-        grid=grid,
+        functools.partial(kernel, dx=dx, bt=bt, taps=common.x_taps(dx)),
+        grid=(c, nb),
         in_specs=[
-            common.lut_spec(wx.shape),
-            common.lut_spec(wy.shape),
-            common.lut_spec(wz.shape),
-            common.full_grid_spec(gp.shape),
+            pl.BlockSpec((ny, y), lambda ch, i: (0, 0)),
+            pl.BlockSpec((z, nz), lambda ch, i: (0, 0)),
+            pl.BlockSpec((1, bt * dx, y, z), lambda ch, i: (ch, i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec(
-            (bx, by, bz, c), lambda i, j, k: (i, j, k, 0)
-        ),
-        out_shape=out_shape,
+        out_specs=pl.BlockSpec((1, nxp, ny, nz), lambda ch, i: (ch, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((c, nxp, ny, nz), jnp.float32),
+        scratch_shapes=scratch,
+        compiler_params=common.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(wx, wy, wz, gp)
-
-
-def _kernel_matmul(b_ref, g_ref, out_ref, *, tile, block_ctrl):
-    dx, dy, dz = tile
-    bx, by, bz = block_ctrl
-    c = out_ref.shape[-1]
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    k = pl.program_id(2)
-    win = g_ref[
-        pl.ds(i * bx * dx, (bx + 3) * dx),
-        pl.ds(j * by * dy, (by + 3) * dy),
-        pl.ds(k * bz * dz, (bz + 3) * dz),
-        :,
-    ].astype(jnp.float32)  # fp32 on-chip accumulation for bf16 cotangents
-    b = b_ref[...].astype(jnp.float32)  # (dx*dy*dz, 64)
-
-    # per-tile layout: (tiles, d^3, C) — each padded tile's voxel cotangents
-    # as one column block of the transposed product
-    u = win.reshape(bx + 3, dx, by + 3, dy, bz + 3, dz, c)
-    u = u.transpose(0, 2, 4, 1, 3, 5, 6).reshape(-1, dx * dy * dz, c)
-    c4 = jax.lax.dot_general(
-        b, u, (((0,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (64, tiles, C): c4[k, t] = sum_v B[v, k] * g[t, v]
-    c4 = c4.reshape(4, 4, 4, bx + 3, by + 3, bz + 3, c)
-    # shifted overlap-adds, one axis at a time: band (l, m, n) of tile t
-    # lands on control point t + (l, m, n) - 3 (transpose of the forward's
-    # phi[t + (l, m, n)] reads; same geometry as _band_sum)
-    h = sum(c4[l, :, :, 3 - l : 3 - l + bx] for l in range(4))
-    h = sum(h[m, :, :, 3 - m : 3 - m + by] for m in range(4))
-    h = sum(h[n, :, :, 3 - n : 3 - n + bz] for n in range(4))
-    out_ref[...] = h.astype(out_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("tile", "block_ctrl", "out_dtype", "interpret")
-)
-def bsi_adjoint_matmul_pallas(gp, b, *, tile, block_ctrl,
-                              out_dtype=jnp.float32, interpret=True):
-    """Transposed-matmul adjoint: same contract as the separable kernel.
-
-    Identical padding/window scheme and output as
-    :func:`bsi_adjoint_separable_pallas`, but the per-block reduction is one
-    ``(64, d^3) @ (d^3, tiles*C)`` MXU contraction against the Kronecker
-    basis ``b`` (``repro.core.bspline.basis_matrix``) instead of three
-    per-axis sweeps.
-    """
-    dx, dy, dz = tile
-    c = gp.shape[3]
-    nx, ny, nz = (s // d - 3 for s, d in zip(gp.shape[:3], tile))
-    bx, by, bz = block_ctrl
-    assert nx % bx == 0 and ny % by == 0 and nz % bz == 0, (gp.shape, block_ctrl)
-    grid = (nx // bx, ny // by, nz // bz)
-    out_shape = jax.ShapeDtypeStruct((nx, ny, nz, c), out_dtype)
-    return pl.pallas_call(
-        functools.partial(_kernel_matmul, tile=tile, block_ctrl=block_ctrl),
-        grid=grid,
-        in_specs=[
-            common.lut_spec(b.shape),
-            common.full_grid_spec(gp.shape),
-        ],
-        out_specs=pl.BlockSpec(
-            (bx, by, bz, c), lambda i, j, k: (i, j, k, 0)
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(b, gp)
+    )(ayt, az, g)

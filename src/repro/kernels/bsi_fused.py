@@ -1,5 +1,9 @@
 """Fused level-step Pallas kernel: BSI + warp + similarity in one VMEM pass.
 
+Runs under the Pallas interpreter only: its warp is a 3-D gather from the
+VMEM moving volume, which Mosaic does not lower on a TPU, so
+``kernels.ops.fused_supported`` refuses it there.
+
 The paper's thesis is that B-spline interpolation is memory-bound — wins come
 from "minimizing the data that needs to be moved between memory and
 processing cores".  The unfused level step moves a lot: it writes the dense
@@ -10,7 +14,7 @@ is still in VMEM:
 
 * the control grid is pinned in VMEM (one HBM load total, as in the forward
   kernels) and each Pallas grid cell evaluates its block's displacement with
-  the separable sweeps of ``bsi_separable``;
+  three per-axis LUT sweeps (``core.interpolate.bsi_separable``'s form);
 * the moving and fixed volumes are pinned in VMEM too, so the warp is a
   VMEM gather at ``identity + displacement`` (clamped trilinear — exactly
   ``core.ffd.warp_volume``'s sampling);
@@ -46,14 +50,47 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import common
-from repro.kernels.bsi_matmul import contract_window, kron_basis
 
 __all__ = ["bsi_fused_pallas", "fused_out_shape", "SCALAR_LANES"]
 
 # Width of the (1, SCALAR_LANES) rows used for scalar partial sums and for
 # the host->kernel statistics operand (mean / min-max of the warped volume).
 SCALAR_LANES = 8
+
+
+def _whole(shape):
+    """BlockSpec handing every grid cell the whole array."""
+    nd = len(shape)
+    return pl.BlockSpec(shape, lambda i, j, k: (0,) * nd)
+
+
+def kron_basis(wx, wy, wz):
+    """The ``(dx*dy*dz, 64)`` Kronecker basis of the three per-axis LUTs
+    (in-kernel twin of ``repro.core.bspline.basis_matrix``)."""
+    dx, dy, dz = wx.shape[0], wy.shape[0], wz.shape[0]
+    b = (wx.reshape(dx, 1, 1, 4, 1, 1)
+         * wy.reshape(1, dy, 1, 1, 4, 1)
+         * wz.reshape(1, 1, dz, 1, 1, 4))
+    return b.reshape(dx * dy * dz, 64)
+
+
+def contract_window(win, b, tile, block_tiles):
+    """Evaluate a ``(bx+3, by+3, bz+3, C)`` halo window as one contraction
+    against the basis; returns the fp32 ``(bx*dx, by*dy, bz*dz, C)`` block."""
+    dx, dy, dz = tile
+    bx, by, bz = block_tiles
+    c = win.shape[-1]
+    cols = jnp.stack([
+        win[l : l + bx, m : m + by, n : n + bz].reshape(-1)
+        for l in range(4) for m in range(4) for n in range(4)
+    ])  # (64, bx*by*bz*C)
+    h = jax.lax.dot_general(
+        b, cols, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # (dx*dy*dz, bx*by*bz*C)
+    h = h.reshape(dx, dy, dz, bx, by, bz, c)
+    h = h.transpose(3, 0, 4, 1, 5, 2, 6)
+    return h.reshape(bx * dx, by * dy, bz * dz, c)
 
 
 def fused_out_shape(sim):
@@ -68,8 +105,8 @@ def _disp_block(phi_ref, wx, wy, wz, *, tile, block_tiles, extra,
                 form="separable"):
     """This cell's displacement block via the selected BSI contraction.
 
-    ``form="separable"`` runs the contraction of ``bsi_separable._kernel``;
-    ``form="matmul"`` runs ``bsi_matmul``'s single MXU contraction against
+    ``form="separable"`` runs three per-axis LUT sweeps;
+    ``form="matmul"`` runs one contraction against
     the Kronecker basis (built in-kernel from the same three LUT refs — tiny
     at ``64 * d^3`` elements).  Either way the block is *extended* by
     ``extra`` tiles per axis (LNCC's window halo; zero elsewhere).  Returns
@@ -267,7 +304,7 @@ def _fused_kernel(wx_ref, wy_ref, wz_ref, sc_ref, phi_ref, mov_ref, fix_ref,
     "tile", "block_tiles", "extra", "vol_shape", "sim", "interpret",
     "disp_form"))
 def bsi_fused_pallas(phi, mov, fix, wx, wy, wz, scalars, *, tile, block_tiles,
-                     extra, vol_shape, sim, interpret=True,
+                     extra, vol_shape, sim, interpret,
                      disp_form="separable"):
     """Run the fused level-step kernel; returns the partial-sum block.
 
@@ -294,13 +331,13 @@ def bsi_fused_pallas(phi, mov, fix, wx, wy, wz, scalars, *, tile, block_tiles,
                           disp_form=disp_form),
         grid=grid,
         in_specs=[
-            common.lut_spec(wx.shape),
-            common.lut_spec(wy.shape),
-            common.lut_spec(wz.shape),
-            common.lut_spec(scalars.shape),
-            common.full_grid_spec(phi.shape),
-            common.lut_spec(mov.shape),
-            common.lut_spec(fix.shape),
+            _whole(wx.shape),
+            _whole(wy.shape),
+            _whole(wz.shape),
+            _whole(scalars.shape),
+            _whole(phi.shape),
+            _whole(mov.shape),
+            _whole(fix.shape),
         ],
         out_specs=pl.BlockSpec(out_shape, lambda i, j, k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),

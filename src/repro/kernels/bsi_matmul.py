@@ -1,23 +1,17 @@
-"""Matrix-form BSI Pallas kernel: one MXU matmul per tile block.
+"""Matrix-form BSI Pallas kernel: every dense plane as ``Ay @ R @ Az^T``.
 
 Wu & Zou ("Matrix representation and GPU-optimized parallel B-spline
-computing") recast uniform B-spline evaluation as small dense matrix
-products.  On the aligned grid the three per-axis ``(d, 4)`` LUTs collapse
-into one ``(dx*dy*dz, 64)`` Kronecker basis ``B`` (precomputed once per
-(tile, dtype), :func:`repro.core.bspline.basis_matrix`), and a whole tile
-block evaluates as a single contraction
+computing") write a uniform B-spline surface as a product of basis
+matrices, ``S = U M P M^T V^T``.  On the voxel-aligned grid the basis
+matrices of the y and z axes are the banded expansion matrices of
+``kernels.common``, so each dense x-plane is
 
-    out[v, (t, ch)] = sum_k B[v, k] * win[k, (t, ch)]
+    out[x] = Ay @ R_x @ Az^T,   R_x = sum_l Wx[x % dx, l] * phi[x // dx + l]
 
-— a ``(d^3, 64) @ (64, tiles*C)`` ``dot_general`` that Mosaic places on the
-MXU with fp32 accumulation (``preferred_element_type``), so bf16 control
-grids keep bf16 operand traffic but fp32 partial sums.  Where the other
-kernels stream gathers and elementwise FMAs through the VPU, this mode
-feeds the matrix units the registration hot loop otherwise leaves idle.
-
-``contract_window``/``kron_basis`` are shared with the fused level-step
-megakernel (``bsi_fused.py``), whose displacement stage can run this same
-contraction behind its ``disp_form`` flag.
+— the x sweep runs first, on the small control planes (VPU), and the whole
+dense plane is then two MXU matmuls.  Against the separable kernel this
+trades VPU work on dense planes for MXU work: ``dx`` plane products per
+tile instead of about one.
 """
 from __future__ import annotations
 
@@ -29,73 +23,42 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import common
 
-__all__ = ["bsi_matmul_pallas", "contract_window", "kron_basis"]
+__all__ = ["bsi_matmul_pallas"]
+
+def _kernel(ay_ref, azt_ref, phi_ref, out_ref, *, dx, bt, taps):
+    t0 = pl.program_id(1) * bt
+    ay = ay_ref[...]
+    azt = azt_ref[...]
+
+    def tile(j, carry):
+        p = [phi_ref[0, t0 + j + l].astype(jnp.float32) for l in range(4)]
+        for a, w in enumerate(taps):
+            r = w[0] * p[0] + w[1] * p[1] + w[2] * p[2] + w[3] * p[3]
+            h = common.mxu_dot(r.astype(azt.dtype), azt)
+            row = common.mxu_dot(ay, h.astype(ay.dtype))
+            out_ref[0, j * dx + a] = row.astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, bt, tile, 0)
 
 
-def kron_basis(wx, wy, wz):
-    """Build the ``(dx*dy*dz, 64)`` Kronecker basis from per-axis LUTs.
-
-    In-kernel equivalent of ``repro.core.bspline.basis_matrix`` (tiny:
-    ``64 * d^3`` elements), used by the fused kernel so its operand
-    interface stays the three ``(d, 4)`` LUT refs every other stage shares.
-    """
-    dx, dy, dz = wx.shape[0], wy.shape[0], wz.shape[0]
-    b = (wx.reshape(dx, 1, 1, 4, 1, 1)
-         * wy.reshape(1, dy, 1, 1, 4, 1)
-         * wz.reshape(1, 1, dz, 1, 1, 4))
-    return b.reshape(dx * dy * dz, 64)
-
-
-def contract_window(win, b, tile, block_tiles):
-    """Evaluate a halo window as one MXU contraction against the basis.
-
-    ``win`` is this grid cell's ``(bx+3, by+3, bz+3, C)`` control window,
-    ``b`` the ``(dx*dy*dz, 64)`` basis.  The 64 ``(l, m, n)`` shifts of the
-    window become the column matrix (the per-tile 4x4x4 support, laid out
-    so channels are contiguous), one ``dot_general`` contracts them, and
-    the ``(voxel-offset, tile)`` axes interleave back into the
-    ``(bx*dx, by*dy, bz*dz, C)`` fp32 output block.
-    """
-    dx, dy, dz = tile
-    bx, by, bz = block_tiles
-    c = win.shape[-1]
-    cols = jnp.stack([
-        win[l : l + bx, m : m + by, n : n + bz].reshape(-1)
-        for l in range(4) for m in range(4) for n in range(4)
-    ])  # (64, bx*by*bz*C)
-    h = jax.lax.dot_general(
-        b, cols, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (dx*dy*dz, bx*by*bz*C)
-    h = h.reshape(dx, dy, dz, bx, by, bz, c)
-    h = h.transpose(3, 0, 4, 1, 5, 2, 6)
-    return h.reshape(bx * dx, by * dy, bz * dz, c)
-
-
-def _kernel(b_ref, phi_ref, out_ref, *, tile, block_tiles):
-    win = common.phi_window(phi_ref, block_tiles)  # (bx+3, by+3, bz+3, C)
-    out = contract_window(win, b_ref[...], tile, block_tiles)
-    out_ref[...] = out.astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("tile", "block_tiles", "interpret"))
-def bsi_matmul_pallas(phi, b, *, tile, block_tiles, interpret=True):
-    tx, ty, tz = (int(n) - 3 for n in phi.shape[:3])
-    c = phi.shape[3]
-    bx, by, bz = block_tiles
-    assert tx % bx == 0 and ty % by == 0 and tz % bz == 0, (phi.shape, block_tiles)
-    grid = (tx // bx, ty // by, tz // bz)
-    out_shape = jax.ShapeDtypeStruct(
-        (tx * tile[0], ty * tile[1], tz * tile[2], c), phi.dtype
-    )
+@functools.partial(jax.jit, static_argnames=("dx", "bt", "interpret"))
+def bsi_matmul_pallas(phi, ay, azt, *, dx, bt, interpret):
+    """Same contract as ``bsi_separable.bsi_separable_pallas``."""
+    c, nxp, ny, nz = phi.shape
+    y, z = ay.shape[0], azt.shape[1]
+    nb = (nxp - 3) // bt
+    assert nb * bt + 3 == nxp, (phi.shape, bt)
     return pl.pallas_call(
-        functools.partial(_kernel, tile=tile, block_tiles=block_tiles),
-        grid=grid,
+        functools.partial(_kernel, dx=dx, bt=bt, taps=common.x_taps(dx)),
+        grid=(c, nb),
         in_specs=[
-            common.lut_spec(b.shape),
-            common.full_grid_spec(phi.shape),
+            pl.BlockSpec((y, ny), lambda ch, i: (0, 0)),
+            pl.BlockSpec((nz, z), lambda ch, i: (0, 0)),
+            pl.BlockSpec((1, nxp, ny, nz), lambda ch, i: (ch, 0, 0, 0)),
         ],
-        out_specs=common.out_spec(block_tiles, tile, c),
-        out_shape=out_shape,
+        out_specs=pl.BlockSpec((1, bt * dx, y, z), lambda ch, i: (ch, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((c, nb * bt * dx, y, z), phi.dtype),
+        compiler_params=common.compiler_params("parallel", "parallel"),
         interpret=interpret,
-    )(b, phi)
+    )(ay, azt, phi)

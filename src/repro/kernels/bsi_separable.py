@@ -1,15 +1,17 @@
-"""Separable (tensor-contraction) BSI Pallas kernel — beyond the paper.
+"""Separable BSI Pallas kernel: control planes first, then the x taps.
 
-The aligned-grid weighted sum is a Tucker contraction:
+The aligned-grid weighted sum is a Tucker contraction,
 
-    out[a,b,c] = sum_{l,m,n} Wx[a,l] * Wy[b,m] * Wz[c,n] * phi[l,m,n]
+    out[x,y,z] = sum_{l,m,n} Wx[x%dx,l] Wy[y%dy,m] Wz[z%dz,n]
+                             * phi[x//dx+l, y//dy+m, z//dz+n],
 
-so instead of 64 MACs per voxel (TT) or 63 lerps (TTLI), three per-axis
-sweeps cost ``4 + 16/d + 64/d^2`` MACs per voxel — for the default 5^3 tile
-**1220 MACs per 125-voxel tile vs 8000** (6.6x fewer FLOPs, ->16x as d grows).
-Each sweep is a small ``dot_general`` that XLA/Mosaic places on the MXU.
-This is the paper's operand-regrouping idea pushed to its limit on a
-systolic-array machine (DESIGN.md §2).
+so it runs as three per-axis sweeps instead of 64 MACs per voxel.  In the
+plane layout of ``kernels.common`` the y and z sweeps of one control plane
+are the two MXU matmuls ``Q = Ay @ P @ Az^T`` (banded matrices), and the x
+sweep combines four consecutive ``Q`` planes with the LUT taps on the VPU.
+Each grid cell owns ``bt`` x-tiles: it expands its ``bt + 3`` control planes
+(the paper's halo window, Eq. A.4, now along x) into a VMEM scratch, then
+writes its ``bt * dx`` dense planes exactly once.
 """
 from __future__ import annotations
 
@@ -18,65 +20,57 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 
 __all__ = ["bsi_separable_pallas"]
 
+def _kernel(ay_ref, azt_ref, phi_ref, out_ref, q_ref, *, dx, bt, taps):
+    t0 = pl.program_id(1) * bt
+    ay = ay_ref[...]
+    azt = azt_ref[...]
 
-def _kernel(wx_ref, wy_ref, wz_ref, phi_ref, out_ref, *, tile, block_tiles):
-    dx, dy, dz = tile
-    bx, by, bz = block_tiles
-    c = out_ref.shape[-1]
-    win = common.phi_window(phi_ref, block_tiles)  # (bx+3, by+3, bz+3, C)
-    wx = wx_ref[...]
-    wy = wy_ref[...]
-    wz = wz_ref[...]
+    def expand(j, carry):
+        h = common.mxu_dot(phi_ref[0, t0 + j], azt)
+        q_ref[j] = common.mxu_dot(ay, h.astype(ay.dtype))
+        return carry
 
-    # x sweep: (4, bx, Y, Z, C) x (dx, 4) -> (bx, dx, Y, Z, C)
-    px = jnp.stack([win[l : l + bx] for l in range(4)])
-    h = jax.lax.dot_general(
-        wx, px.reshape(4, -1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(dx, bx, by + 3, bz + 3, c)
-    h = jnp.moveaxis(h, 0, 1).reshape(bx * dx, by + 3, bz + 3, c)
-    # y sweep
-    py = jnp.stack([h[:, m : m + by] for m in range(4)])
-    h = jax.lax.dot_general(
-        wy, py.reshape(4, -1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(dy, bx * dx, by, bz + 3, c)
-    h = jnp.moveaxis(h, 0, 2).reshape(bx * dx, by * dy, bz + 3, c)
-    # z sweep
-    pz = jnp.stack([h[:, :, n : n + bz] for n in range(4)])
-    h = jax.lax.dot_general(
-        wz, pz.reshape(4, -1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(dz, bx * dx, by * dy, bz, c)
-    h = jnp.moveaxis(h, 0, 3).reshape(bx * dx, by * dy, bz * dz, c)
-    out_ref[...] = h.astype(out_ref.dtype)
+    jax.lax.fori_loop(0, bt + 3, expand, 0)
+
+    def tile(j, carry):
+        q = [q_ref[j + l] for l in range(4)]
+        for a, w in enumerate(taps):
+            row = w[0] * q[0] + w[1] * q[1] + w[2] * q[2] + w[3] * q[3]
+            out_ref[0, j * dx + a] = row.astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, bt, tile, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "block_tiles", "interpret"))
-def bsi_separable_pallas(phi, wx, wy, wz, *, tile, block_tiles, interpret=True):
-    tx, ty, tz = (int(n) - 3 for n in phi.shape[:3])
-    c = phi.shape[3]
-    bx, by, bz = block_tiles
-    assert tx % bx == 0 and ty % by == 0 and tz % bz == 0, (phi.shape, block_tiles)
-    grid = (tx // bx, ty // by, tz // bz)
-    out_shape = jax.ShapeDtypeStruct(
-        (tx * tile[0], ty * tile[1], tz * tile[2], c), phi.dtype
-    )
+@functools.partial(jax.jit, static_argnames=("dx", "bt", "interpret"))
+def bsi_separable_pallas(phi, ay, azt, *, dx, bt, interpret):
+    """Planes-layout forward: ``phi (C, nb*bt+3, Ny, Nz)`` -> ``(C, nb*bt*dx, Y, Z)``.
+
+    ``ay`` is the ``(Y, Ny)`` and ``azt`` the ``(Nz, Z)`` banded matrix
+    (``common.band_matrix``); ``Ny, Nz, Y, Z`` are already padded to
+    ``(8, 128)`` multiples by ``kernels.ops``.
+    """
+    c, nxp, ny, nz = phi.shape
+    y, z = ay.shape[0], azt.shape[1]
+    nb = (nxp - 3) // bt
+    assert nb * bt + 3 == nxp, (phi.shape, bt)
     return pl.pallas_call(
-        functools.partial(_kernel, tile=tile, block_tiles=block_tiles),
-        grid=grid,
+        functools.partial(_kernel, dx=dx, bt=bt, taps=common.x_taps(dx)),
+        grid=(c, nb),
         in_specs=[
-            common.lut_spec(wx.shape),
-            common.lut_spec(wy.shape),
-            common.lut_spec(wz.shape),
-            common.full_grid_spec(phi.shape),
+            pl.BlockSpec((y, ny), lambda ch, i: (0, 0)),
+            pl.BlockSpec((nz, z), lambda ch, i: (0, 0)),
+            pl.BlockSpec((1, nxp, ny, nz), lambda ch, i: (ch, 0, 0, 0)),
         ],
-        out_specs=common.out_spec(block_tiles, tile, c),
-        out_shape=out_shape,
+        out_specs=pl.BlockSpec((1, bt * dx, y, z), lambda ch, i: (ch, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((c, nb * bt * dx, y, z), phi.dtype),
+        scratch_shapes=[pltpu.VMEM((bt + 3, y, z), jnp.float32)],
+        compiler_params=common.compiler_params("parallel", "parallel"),
         interpret=interpret,
-    )(wx, wy, wz, phi)
+    )(ay, azt, phi)

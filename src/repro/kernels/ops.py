@@ -1,10 +1,14 @@
 """Jit'd dispatch wrappers for the BSI Pallas kernels.
 
-Handles the plumbing the kernels don't: LUT construction, padding the tile
-count up to a block multiple (padded control points never reach the cropped
-output), block-size selection under the VMEM budget, and z-chunking when a
-control grid exceeds VMEM (the rare >16 MB grid case; the chunk halo is the
-level-2 instance of the paper's Eq. A.4 overlap scheme).
+Handles the plumbing the kernels don't: the channel-first, ``(8, 128)``-padded
+plane layout of ``kernels.common`` (one XLA transpose+pad in, one slice+
+transpose out), the banded y/z expansion matrices, padding the x tile count
+up to whole blocks (padded control planes never reach the cropped output),
+and the x block size, picked so a grid cell's VMEM blocks fit
+``common.VMEM_BUDGET_BYTES``.
+
+``interpret`` is never a caller's choice: kernels compile on a TPU backend
+and run under the Pallas interpreter everywhere else (:func:`default_interpret`).
 """
 from __future__ import annotations
 
@@ -13,89 +17,108 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.bspline import basis_matrix, lerp_luts, weight_lut
-from repro.kernels.bsi_adjoint import (bsi_adjoint_matmul_pallas,
-                                       bsi_adjoint_separable_pallas)
+from repro.core.bspline import weight_lut
+from repro.kernels import common
+from repro.kernels.bsi_adjoint import bsi_adjoint_pallas_planes
 from repro.kernels.bsi_fused import SCALAR_LANES, bsi_fused_pallas
 from repro.kernels.bsi_matmul import bsi_matmul_pallas
 from repro.kernels.bsi_separable import bsi_separable_pallas
-from repro.kernels.bsi_tt import bsi_tt_pallas
-from repro.kernels.bsi_ttli import bsi_ttli_pallas
 
-__all__ = ["PALLAS_MODES", "FUSED_SIM_KINDS", "bsi_pallas",
-           "bsi_adjoint_pallas", "fused_similarity_loss", "fused_supported",
-           "default_interpret", "pick_block_tiles"]
+__all__ = ["PALLAS_MODES", "NO_KERNEL", "FUSED_SIM_KINDS", "FUSED_NO_TPU",
+           "bsi_pallas", "bsi_adjoint_pallas", "fused_similarity_loss",
+           "fused_supported", "default_interpret", "pick_block_tiles",
+           "pick_block_ctrl"]
 
-# Modes with a Pallas kernel (``gather`` has none — it is the baseline the
-# kernels beat).  The engine autotuner enumerates its candidates from this.
-PALLAS_MODES = ("tt", "ttli", "separable", "matmul")
+# Modes with a Pallas kernel.  The engine autotuner enumerates its Pallas
+# candidates from this; every other mode runs as its jnp form only.
+PALLAS_MODES = ("separable", "matmul")
+# Why the other modes have no kernel.
+NO_KERNEL = {
+    "gather": "the per-voxel gather is the baseline the kernels beat",
+    "tt": ("the 64-term weighted sum interleaves tile and voxel offsets on "
+           "every axis, a lane shuffle Mosaic does not lower on a TPU; the "
+           "separable and matmul kernels fold that interleave into banded "
+           "MXU matmuls instead"),
+    "ttli": ("the lerp form interleaves tile and voxel offsets on every "
+             "axis, a lane shuffle Mosaic does not lower on a TPU; the "
+             "separable and matmul kernels fold that interleave into banded "
+             "MXU matmuls instead"),
+}
 
-# Budget for (control grid + out block + window temporaries) in VMEM.
-_VMEM_BUDGET_BYTES = 12 * 2**20
+# Budget for the fused kernel's VMEM-pinned volumes (see fused_supported).
+_FUSED_VMEM_BUDGET_BYTES = 12 * 2**20
 _DEFAULT_BLOCK_TILES = (4, 4, 4)  # cubes maximise halo overlap (paper §3.4)
-
-
-def _shrink_to_budget(limits, bytes_fn, budget):
-    """Clamp the default block to ``limits``, then halve the largest axis
-    until ``bytes_fn(block)`` fits half the budget (or every axis is 1).
-
-    The clamp means tiny grids never budget for (and pad up to) blocks
-    larger than the whole grid.  Shared by the forward (tile-block) and
-    adjoint (control-point-block) pickers, which differ only in what the
-    block's bytes are.
-    """
-    b = [min(d, max(1, int(n))) for d, n in zip(_DEFAULT_BLOCK_TILES, limits)]
-    while bytes_fn(b) >= budget // 2 and max(b) > 1:
-        b[b.index(max(b))] = max(1, max(b) // 2)
-    return tuple(b)
-
-
-def pick_block_tiles(num_tiles, tile, channels, itemsize, budget=_VMEM_BUDGET_BYTES):
-    """Pick a tile-block shape: cube-ish, bounded by the VMEM budget."""
-
-    def block_bytes(bt):
-        out = bt[0] * tile[0] * bt[1] * tile[1] * bt[2] * tile[2]
-        win = (bt[0] + 3) * (bt[1] + 3) * (bt[2] + 3)
-        return (out + 8 * win) * channels * itemsize
-
-    return _shrink_to_budget(num_tiles, block_bytes, budget)
-
-
-def _pad_tiles(phi, num_tiles, block_tiles):
-    pads = []
-    for t, b in zip(num_tiles, block_tiles):
-        pads.append((0, (-t) % b))
-    pads.append((0, 0))
-    if any(p[1] for p in pads):
-        phi = jnp.pad(phi, pads)
-    return phi, tuple(t + p[1] for t, p in zip(num_tiles, pads))
+_MAX_X_BLOCK = 8  # x tiles per grid cell of the BSI kernels, at most
 
 
 def default_interpret() -> bool:
-    """Whether the kernels need ``interpret=True`` on the current backend.
+    """Whether the kernels run under the Pallas interpreter here.
 
-    Pallas TPU kernels compile only on TPU; everywhere else (CPU CI, GPU
-    hosts) they run under the interpreter.  Resolving this from
-    ``jax.default_backend()`` lets callers leave ``interpret`` unset and
-    still get compiled kernels on real hardware.
+    Pallas TPU kernels compile only on a TPU backend; everywhere else (CPU
+    CI, GPU hosts) they run under the interpreter.  Every dispatcher resolves
+    ``interpret`` from this, so no kernel runs interpreted on a TPU.
     """
     return jax.default_backend() != "tpu"
 
 
-def bsi_pallas(phi, tile, *, mode="ttli", dtype=None, block_tiles=None,
-               interpret=None):
+def _planes(num_tiles, tile):
+    """Padded plane extents ``(Y, Z, Ny, Nz)``: dense and control."""
+    (_, ty, tz), (_, dy, dz) = num_tiles, tile
+    return (common.round_up(ty * dy, common.SUBLANE),
+            common.round_up(tz * dz, common.LANE),
+            common.round_up(ty + 3, common.SUBLANE),
+            common.round_up(tz + 3, common.LANE))
+
+
+def _block_bytes(bt, num_tiles, tile, itemsize, *, adjoint):
+    """VMEM a grid cell of the forward (or adjoint) kernels plans for.
+
+    Double-buffered dense block and resident control block, the two band
+    matrices, the ``bt + 3``-plane scratch window and a few plane-sized
+    temporaries; every slab counted after ``(8, 128)`` tiling.
+    """
+    y, z, ny, nz = _planes(num_tiles, tile)
+    dx = tile[0]
+    nxp = -(-num_tiles[0] // bt) * bt + 3
+    dense = common.plane_bytes(y, z)
+    ctrl = common.plane_bytes(ny, nz)
+    mats = 2 * (common.plane_bytes(y, ny) + common.plane_bytes(nz, z))
+    io = (2 * bt * dx * common.plane_bytes(y, z, itemsize)
+          + 2 * nxp * (ctrl if adjoint
+                       else common.plane_bytes(ny, nz, itemsize)))
+    return io + mats + (bt + 3) * dense + 6 * dense + 8 * ctrl
+
+
+def pick_block_tiles(num_tiles, tile, itemsize=4,
+                     budget=common.VMEM_BUDGET_BYTES, *, adjoint=False):
+    """x tiles per grid cell: the largest (up to 8) whose VMEM fits ``budget``.
+
+    Never below 1: a grid whose resident planes alone overflow VMEM is left
+    to the compiler, which refuses it with an out-of-memory error.
+    """
+    for bt in range(min(_MAX_X_BLOCK, int(num_tiles[0])), 0, -1):
+        if _block_bytes(bt, num_tiles, tile, itemsize,
+                        adjoint=adjoint) <= budget:
+            return bt
+    return 1
+
+
+def pick_block_ctrl(num_tiles, tile, itemsize=4,
+                    budget=common.VMEM_BUDGET_BYTES):
+    """x tiles per grid cell of the adjoint kernels (see pick_block_tiles)."""
+    return pick_block_tiles(num_tiles, tile, itemsize, budget, adjoint=True)
+
+
+def bsi_pallas(phi, tile, *, mode="separable", dtype=None, block_tiles=None):
     """Run one of the BSI Pallas kernels on a stored control grid.
 
     Args match ``repro.core.interpolate.interpolate``; ``mode`` selects the
-    kernel (``tt`` | ``ttli`` | ``separable`` | ``matmul``; ``gather`` has
-    no kernel — it is the baseline the kernels beat).  ``interpret``
-    defaults to
-    :func:`default_interpret` — compiled on TPU, interpreter elsewhere.
+    kernel (``separable`` | ``matmul``; :data:`NO_KERNEL` says why the other
+    modes have none).  ``block_tiles`` overrides the x tiles per grid cell.
     """
-    if interpret is None:
-        interpret = default_interpret()
-    return _bsi_pallas_jit(phi, tile, mode=mode, dtype=dtype,
-                           block_tiles=block_tiles, interpret=bool(interpret))
+    return _bsi_pallas_jit(phi, tuple(int(t) for t in tile), mode=mode,
+                           dtype=dtype, block_tiles=block_tiles,
+                           interpret=default_interpret())
 
 
 @functools.partial(
@@ -103,130 +126,77 @@ def bsi_pallas(phi, tile, *, mode="ttli", dtype=None, block_tiles=None,
 )
 def _bsi_pallas_jit(phi, tile, *, mode, dtype, block_tiles, interpret):
     if mode not in PALLAS_MODES:
-        raise ValueError(f"no Pallas kernel for mode {mode!r}")
+        raise ValueError(f"no Pallas kernel for mode {mode!r}: "
+                         f"{NO_KERNEL.get(mode, 'unknown mode')}")
     if dtype is not None:
         phi = phi.astype(dtype)
-    tile = tuple(int(t) for t in tile)
     num_tiles = tuple(int(n) - 3 for n in phi.shape[:3])
-    c = phi.shape[3]
-    if block_tiles is None:
-        block_tiles = pick_block_tiles(num_tiles, tile, c, phi.dtype.itemsize)
-    block_tiles = tuple(min(b, t) for b, t in zip(block_tiles, num_tiles))
-    phi_p, padded_tiles = _pad_tiles(phi, num_tiles, block_tiles)
-
-    if mode == "tt":
-        luts = tuple(weight_lut(d, phi.dtype) for d in tile)
-        out = bsi_tt_pallas(
-            phi_p, *luts, tile=tile, block_tiles=block_tiles, interpret=interpret
-        )
-    elif mode == "ttli":
-        luts = tuple(jnp.stack(lerp_luts(d, phi.dtype)) for d in tile)
-        out = bsi_ttli_pallas(
-            phi_p, *luts, tile=tile, block_tiles=block_tiles, interpret=interpret
-        )
-    elif mode == "separable":
-        luts = tuple(weight_lut(d, phi.dtype) for d in tile)
-        out = bsi_separable_pallas(
-            phi_p, *luts, tile=tile, block_tiles=block_tiles, interpret=interpret
-        )
-    elif mode == "matmul":
-        b = basis_matrix(tile, phi.dtype)
-        out = bsi_matmul_pallas(
-            phi_p, b, tile=tile, block_tiles=block_tiles, interpret=interpret
-        )
-    else:  # unreachable: PALLAS_MODES checked above; keep dispatch explicit
-        raise ValueError(f"no Pallas kernel for mode {mode!r}")
-    return out[
-        : num_tiles[0] * tile[0], : num_tiles[1] * tile[1], : num_tiles[2] * tile[2]
-    ]
+    (tx, ty, tz), (dx, dy, dz) = num_tiles, tile
+    y, z, ny, nz = _planes(num_tiles, tile)
+    bt = block_tiles or pick_block_tiles(num_tiles, tile, phi.dtype.itemsize)
+    bt = min(int(bt), tx)
+    nb = -(-tx // bt)
+    p = jnp.pad(jnp.transpose(phi, (3, 0, 1, 2)),
+                ((0, 0), (0, nb * bt - tx), (0, ny - ty - 3), (0, nz - tz - 3)))
+    name = phi.dtype.name
+    ay = jnp.asarray(common.band_matrix(ty, dy, y, ny, name))
+    azt = jnp.asarray(common.band_matrix(tz, dz, z, nz, name).T)
+    kern = bsi_separable_pallas if mode == "separable" else bsi_matmul_pallas
+    out = kern(p, ay, azt, dx=dx, bt=bt, interpret=interpret)
+    return jnp.transpose(out[:, : tx * dx, : ty * dy, : tz * dz], (1, 2, 3, 0))
 
 
-def pick_block_ctrl(num_ctrl, tile, channels, itemsize,
-                    budget=_VMEM_BUDGET_BYTES):
-    """Pick the adjoint kernel's control-point block: cube-ish, VMEM-bounded.
-
-    The dominant temporary is the ``((bc+3)*d)^3`` cotangent window each grid
-    cell reduces (read bf16/f32, accumulated f32), so the window is what the
-    budget bounds (4x headroom for the sweep temporaries); the ``bc^3``
-    output block is negligible next to it.
-    """
-
-    def block_bytes(bc):
-        win = ((bc[0] + 3) * tile[0] * (bc[1] + 3) * tile[1]
-               * (bc[2] + 3) * tile[2])
-        return 4 * win * channels * itemsize
-
-    return _shrink_to_budget(num_ctrl, block_bytes, budget)
-
-
-def bsi_adjoint_pallas(g, tile, *, dtype=None, block_ctrl=None,
-                       interpret=None, form="separable"):
+def bsi_adjoint_pallas(g, tile, *, dtype=None, block_tiles=None,
+                       form="separable"):
     """Run the Pallas BSI adjoint: dense cotangent -> control-grid cotangent.
 
     The transpose of :func:`bsi_pallas` (same answer for every forward mode —
     BSI is linear, all modes compute the same function).  ``g`` is the
     ``(Tx*dx, Ty*dy, Tz*dz, C)`` cotangent of the dense field; returns the
     ``(Tx+3, Ty+3, Tz+3, C)`` control-grid cotangent in ``dtype`` (default
-    float32 — fp32 accumulation even for bf16 cotangents).  ``interpret``
-    defaults to :func:`default_interpret`.  ``form`` picks the per-block
-    reduction: ``separable`` (three per-axis sweeps, ``grad_impl="pallas"``)
-    or ``matmul`` (one transposed MXU contraction, ``grad_impl="matmul"``).
-
-    The dispatcher zero-pads ``g`` by 3 tiles per axis so every control
-    point uniformly owns the padded-tile window ``[i, i+4)`` (the adjoint
-    mirror of the forward halo), pads the control count up to a block
-    multiple, and z-chunks the padded cotangent when it exceeds the VMEM
-    budget (the level-2 Eq. A.4 overlap scheme, on the gradient).
+    float32 — fp32 accumulation even for bf16 cotangents).  ``form`` picks
+    the kernel: ``separable`` (``grad_impl="pallas"``) or ``matmul``
+    (``grad_impl="matmul"``), see ``kernels.bsi_adjoint``.
     """
-    if interpret is None:
-        interpret = default_interpret()
     return _bsi_adjoint_jit(g, tuple(int(t) for t in tile), dtype=dtype,
-                            block_ctrl=block_ctrl, interpret=bool(interpret),
-                            form=form)
+                            block_tiles=block_tiles, form=form,
+                            interpret=default_interpret())
 
 
 @functools.partial(
-    jax.jit, static_argnames=("tile", "dtype", "block_ctrl", "interpret", "form")
-)
-def _bsi_adjoint_jit(g, tile, *, dtype, block_ctrl, interpret,
-                     form="separable"):
+    jax.jit, static_argnames=("tile", "dtype", "block_tiles", "form",
+                              "interpret"))
+def _bsi_adjoint_jit(g, tile, *, dtype, block_tiles, form, interpret):
+    if form not in ("separable", "matmul"):
+        raise ValueError(f"unknown adjoint form {form!r}")
     out_dtype = jnp.dtype(dtype) if dtype is not None else jnp.float32
     dx, dy, dz = tile
-    X, Y, Z, c = g.shape
+    X, Y, Z, _ = g.shape
     if X % dx or Y % dy or Z % dz:
         raise ValueError(f"cotangent shape {g.shape} not a multiple of {tile}")
-    num_ctrl = (X // dx + 3, Y // dy + 3, Z // dz + 3)
-    if block_ctrl is None:
-        block_ctrl = pick_block_ctrl(num_ctrl, tile, c, g.dtype.itemsize)
-    block_ctrl = tuple(min(b, n) for b, n in zip(block_ctrl, num_ctrl))
-    # pad: 3 zero tiles per side (uniform windows) + control count up to a
-    # block multiple (the extra rows are cropped from the output).
-    pads = [(3 * d, (3 + (-n) % b) * d)
-            for n, b, d in zip(num_ctrl, block_ctrl, tile)]
-    gp = jnp.pad(g, pads + [(0, 0)])
-    if form == "matmul":
-        b = basis_matrix(tile, jnp.float32)
-        kern = functools.partial(bsi_adjoint_matmul_pallas, b=b)
-    elif form == "separable":
-        luts = tuple(weight_lut(d, jnp.float32) for d in tile)
-        kern = lambda slab, **kw: bsi_adjoint_separable_pallas(  # noqa: E731
-            slab, *luts, **kw)
-    else:
-        raise ValueError(f"unknown adjoint form {form!r}")
+    num_tiles = (X // dx, Y // dy, Z // dz)
+    tx, ty, tz = num_tiles
+    y, z, ny, nz = _planes(num_tiles, tile)
+    bt = block_tiles or pick_block_ctrl(num_tiles, tile, g.dtype.itemsize)
+    bt = min(int(bt), tx)
+    nb = -(-tx // bt)
+    gp = jnp.pad(jnp.transpose(g, (3, 0, 1, 2)),
+                 ((0, 0), (0, (nb * bt - tx) * dx), (0, y - Y), (0, z - Z)))
+    ayt = jnp.asarray(common.band_matrix(ty, dy, y, ny).T)
+    az = jnp.asarray(common.band_matrix(tz, dz, z, nz))
+    out = bsi_adjoint_pallas_planes(gp, ayt, az, dx=dx, bt=bt, form=form,
+                                    interpret=interpret)
+    out = out[:, : tx + 3, : ty + 3, : tz + 3]
+    return jnp.transpose(out, (1, 2, 3, 0)).astype(out_dtype)
 
-    nz_pad = gp.shape[2] // dz - 3  # padded control count along z
-    # budget read at trace time (not def time) so tests can patch it
-    chunk = _pick_z_chunk(gp.shape, nz_pad, block_ctrl[2], gp.dtype.itemsize,
-                          budget=_VMEM_BUDGET_BYTES)
-    outs = []
-    for k0 in range(0, nz_pad, chunk):
-        k1 = min(k0 + chunk, nz_pad)
-        slab = gp[:, :, k0 * dz : (k1 + 3) * dz]
-        outs.append(kern(
-            slab, tile=tile, block_ctrl=block_ctrl,
-            out_dtype=out_dtype, interpret=interpret))
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
-    return out[: num_ctrl[0], : num_ctrl[1], : num_ctrl[2]]
+
+def _shrink_to_budget(limits, bytes_fn, budget):
+    """Clamp the default block to ``limits``, then halve the largest axis
+    until ``bytes_fn(block)`` fits half the budget (or every axis is 1)."""
+    b = [min(d, max(1, int(n))) for d, n in zip(_DEFAULT_BLOCK_TILES, limits)]
+    while bytes_fn(b) >= budget // 2 and max(b) > 1:
+        b[b.index(max(b))] = max(1, max(b) // 2)
+    return tuple(b)
 
 
 # --- fused level step (BSI + warp + similarity, kernels.bsi_fused) ---------
@@ -234,18 +204,25 @@ def _bsi_adjoint_jit(g, tile, *, dtype, block_ctrl, interpret,
 # Similarity kinds with a fused partial-sum accumulator.  The spec tuples
 # come from ``repro.core.similarity.fused_spec`` (first element = kind).
 FUSED_SIM_KINDS = ("ssd", "ncc", "lncc", "nmi")
+# Why the fused kernel cannot run where kernels compile (see fused_supported).
+FUSED_NO_TPU = ("the fused kernel's warp is a 3-D gather from the VMEM "
+                "moving volume, which Mosaic does not lower on a TPU (only "
+                "2-D gathers)")
 
 
 def fused_supported(vol_shape, sim_spec, itemsize=4,
-                    budget=_VMEM_BUDGET_BYTES):
+                    budget=_FUSED_VMEM_BUDGET_BYTES):
     """Whether the fused kernel can run this level: ``(ok, reason)``.
 
     The fused kernel pins the moving *and* fixed volumes in VMEM (the warp
     is a VMEM gather), so it is bounded by volume size, not grid size —
     beyond the budget the unfused tiled kernels remain the path.  The
     similarity must also have a fused accumulator (a registered kind with
-    known parameters; custom callables don't).
+    known parameters; custom callables don't).  Where kernels compile (a TPU
+    backend) it cannot run at any size: :data:`FUSED_NO_TPU`.
     """
+    if not default_interpret():
+        return False, FUSED_NO_TPU
     if sim_spec is None or sim_spec[0] not in FUSED_SIM_KINDS:
         return False, "similarity has no fused accumulator"
     vox = 1
@@ -258,7 +235,7 @@ def fused_supported(vol_shape, sim_spec, itemsize=4,
 
 
 def pick_block_tiles_fused(num_tiles, tile, extra, sim_spec, itemsize,
-                           budget=_VMEM_BUDGET_BYTES):
+                           budget=_FUSED_VMEM_BUDGET_BYTES):
     """Tile-block for the fused kernel: cube-ish, VMEM-bounded.
 
     Per-voxel temporaries dominate: the displacement block plus the eight
@@ -283,7 +260,7 @@ def pick_block_tiles_fused(num_tiles, tile, extra, sim_spec, itemsize,
 
 def fused_similarity_loss(phi, moving, fixed, tile, *, sim_spec,
                           compute_dtype=None, block_tiles=None,
-                          interpret=None, disp_form="separable"):
+                          disp_form="separable"):
     """Similarity loss of the warped moving volume — fused, no dense field.
 
     Computes ``sim(warp(moving, bsi(phi)), fixed)`` where ``sim`` is the
@@ -301,12 +278,11 @@ def fused_similarity_loss(phi, moving, fixed, tile, *, sim_spec,
     Forward only — the differentiable wrapper is
     ``repro.core.ffd.fused_warp_loss``.
     """
-    if interpret is None:
-        interpret = default_interpret()
     cd = None if compute_dtype is None else jnp.dtype(compute_dtype).name
     return _fused_loss_jit(phi, moving, fixed, tuple(int(t) for t in tile),
                            sim_spec=tuple(sim_spec), compute_dtype=cd,
-                           block_tiles=block_tiles, interpret=bool(interpret),
+                           block_tiles=block_tiles,
+                           interpret=default_interpret(),
                            disp_form=disp_form)
 
 
@@ -390,20 +366,3 @@ def _fused_loss_jit(phi, moving, fixed, tile, *, sim_spec, compute_dtype,
     hb = -jnp.sum(pb * jnp.log(pb + eps))
     hab = -jnp.sum(pab * jnp.log(pab + eps))
     return 2.0 - (ha + hb) / (hab + eps)
-
-
-def _pick_z_chunk(gp_shape, nz_pad, bz, itemsize, budget=_VMEM_BUDGET_BYTES):
-    """Largest ``bz``-multiple z-chunk whose cotangent slab fits the budget.
-
-    Each chunk of ``K`` control points re-reads a ``(K+3)``-tile slab — the
-    3-tile halo is the chunk-level instance of the forward's Eq. A.4 overlap.
-    Chunks never go below one block; a single minimal block that still
-    exceeds the budget runs anyway (interpret mode tolerates it; on real
-    hardware that is the signal to shrink ``block_ctrl``).
-    """
-    plane = gp_shape[0] * gp_shape[1] * gp_shape[3] * itemsize
-    dz = gp_shape[2] // (nz_pad + 3)
-    chunk = nz_pad
-    while chunk > bz and (chunk + 3) * dz * plane > budget // 2:
-        chunk = max(bz, (chunk // 2 // bz) * bz)
-    return chunk

@@ -10,6 +10,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh"]
 
@@ -30,4 +31,5 @@ def make_mesh(shape, axes):
             "dry-run set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before importing jax (launch/dryrun.py does this)."
         )
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))

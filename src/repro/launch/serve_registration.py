@@ -101,6 +101,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.core.options import RegistrationOptions
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.engine.convergence import ConvergenceConfig
     from repro.engine.serve import RegistrationScheduler
 

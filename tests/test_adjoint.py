@@ -73,39 +73,30 @@ def test_pallas_forward_differentiable_with_custom_adjoint():
     g = _cotangent(grid, tile, 3, seed=2)
     ref = _grad_of_gather_ref(phi, tile, g)
     got = jax.grad(
-        lambda p: jnp.vdot(interpolate(p, tile, mode="ttli", impl="pallas",
-                                       grad_impl="jnp"), g))(phi)
+        lambda p: jnp.vdot(interpolate(p, tile, mode="separable",
+                                       impl="pallas", grad_impl="jnp"), g))(phi)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
     with pytest.raises(Exception):
-        jax.grad(lambda p: interpolate(p, tile, mode="ttli", impl="pallas",
-                                       grad_impl="xla").sum())(phi)
+        jax.grad(lambda p: interpolate(p, tile, mode="separable",
+                                       impl="pallas", grad_impl="xla").sum())(phi)
 
 
 def test_adjoint_pallas_block_shapes_and_chunking(monkeypatch):
+    """Every x block size (the grid's chunking of the cotangent: cells
+    share three control planes, accumulated in the resident output block)
+    gives the same answer, in both kernel forms — including a VMEM budget
+    so small that the picker falls back to one tile per cell."""
     g = _cotangent((9, 9, 15), (4, 4, 3), 3, seed=7)
     ref = bsi_adjoint_separable(g, (4, 4, 3))
-    for bc in [(1, 1, 1), (2, 2, 2), (4, 2, 1)]:
-        out = ops.bsi_adjoint_pallas(g, (4, 4, 3), block_ctrl=bc)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5)
-    # a tiny budget forces the z-chunked dispatch (several pallas_calls whose
-    # slabs overlap by the 3-tile halo) — answers must not change.  The
-    # post-patch call uses a block_ctrl no earlier call traced with: jit
-    # caches per static-arg signature, so reusing one would silently serve
-    # the unchunked program traced under the default budget.
-    monkeypatch.setattr(ops, "_VMEM_BUDGET_BYTES", 2 * 2**20)
-    picked = {}
-    real_pick = ops._pick_z_chunk
-
-    def spy(gp_shape, nz_pad, bz, itemsize, **kw):
-        picked["chunk"] = real_pick(gp_shape, nz_pad, bz, itemsize, **kw)
-        picked["nz_pad"] = nz_pad
-        return picked["chunk"]
-
-    monkeypatch.setattr(ops, "_pick_z_chunk", spy)
-    out = ops.bsi_adjoint_pallas(g, (4, 4, 3), block_ctrl=(2, 1, 2))
-    assert picked["chunk"] < picked["nz_pad"], picked  # really chunked
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    for form in ("separable", "matmul"):
+        for bt in (1, 2, 4):
+            out = ops.bsi_adjoint_pallas(g, (4, 4, 3), block_tiles=bt,
+                                         form=form)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                       atol=1e-5)
+    assert ops.pick_block_ctrl((6, 6, 12), (4, 4, 3)) == 6
+    one = ops._block_bytes(1, (6, 6, 12), (4, 4, 3), 4, adjoint=True)
+    assert ops.pick_block_ctrl((6, 6, 12), (4, 4, 3), budget=one) == 1
 
 
 def test_adjoint_accumulates_fp32_for_bf16_cotangents():
@@ -280,17 +271,20 @@ def test_autotune_pallas_forward_survives_with_custom_adjoint(tmp_path):
     drops out — but (pallas fwd, jnp adjoint) is a live candidate now."""
     from repro.engine.autotune import autotune_bsi
 
+    from repro.engine.autotune import NO_AUTODIFF
+
     choice = autotune_bsi(
         (7, 7, 7), (2, 2, 2), 2, reps=1, measure_grad=True,
-        candidates=(("ttli", "pallas", "xla"), ("ttli", "pallas", "jnp")),
+        candidates=(("separable", "pallas", "xla"),
+                    ("separable", "pallas", "jnp")),
         cache_path=str(tmp_path / "c.json"))
     assert (choice.mode, choice.impl, choice.grad_impl) == \
-        ("ttli", "pallas", "jnp")
+        ("separable", "pallas", "jnp")
+    assert choice.skipped == (("separable/pallas/xla", NO_AUTODIFF),)
 
 
 def test_pick_block_ctrl_clamps_to_grid():
-    bc = ops.pick_block_ctrl((2, 2, 1), (5, 5, 5), 3, 4)
-    assert bc == (2, 2, 1)
-    big = ops.pick_block_ctrl((64, 64, 64), (7, 7, 7), 3, 4, budget=2**20)
-    win = (big[0] + 3) * 7 * (big[1] + 3) * 7 * (big[2] + 3) * 7 * 3 * 4
-    assert 4 * win < 2**20 // 2 or max(big) == 1
+    assert ops.pick_block_ctrl((2, 2, 1), (5, 5, 5)) == 2
+    big = ops.pick_block_ctrl((64, 64, 64), (7, 7, 7), budget=8 * 2**20)
+    assert ops._block_bytes(big, (64, 64, 64), (7, 7, 7), 4,
+                            adjoint=True) <= 8 * 2**20 or big == 1

@@ -138,3 +138,92 @@ def test_fused_race_entry_round_trips(tmp_path, monkeypatch):
     assert again == first
     entries = json.loads(cache.read_text())["entries"]
     assert any("|fused|" in k for k in entries)
+
+
+def _failing_interpolate(monkeypatch, bad_mode, err):
+    """Make one candidate form raise ``err`` while it is traced."""
+    real = autotune.interpolate
+
+    def fake(p, tile, *, mode, **kw):
+        if mode == bad_mode:
+            raise err
+        return real(p, tile, mode=mode, **kw)
+
+    monkeypatch.setattr(autotune, "interpolate", fake)
+
+
+def test_non_memory_error_propagates_from_autotune_bsi(tmp_path,
+                                                       monkeypatch):
+    """Only out-of-memory skips a candidate: any other failure is a bug the
+    race must not hide behind a slower winner."""
+    _failing_interpolate(monkeypatch, "ttli", TypeError("kernel bug"))
+    autotune._MEM_CACHE.clear()
+    with pytest.raises(TypeError, match="kernel bug"):
+        autotune_bsi(GRID, TILE, 2, reps=1, use_cache=False,
+                     candidates=(("separable", "jnp"), ("ttli", "jnp")))
+
+
+def test_out_of_memory_candidate_is_skipped_with_reason(tmp_path,
+                                                        monkeypatch):
+    import jax
+
+    oom = jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm")
+    _failing_interpolate(monkeypatch, "ttli", oom)
+    cache = tmp_path / "c.json"
+    autotune._MEM_CACHE.clear()
+    choice = autotune_bsi(GRID, TILE, 2, reps=1, cache_path=str(cache),
+                          candidates=(("separable", "jnp"), ("ttli", "jnp")))
+    assert (choice.mode, choice.impl) == ("separable", "jnp")
+    assert choice.skipped == (
+        ("ttli/jnp", "RESOURCE_EXHAUSTED: Ran out of memory in memory "
+                     "space hbm"),)
+    # the skipped list survives the disk cache
+    autotune._MEM_CACHE.clear()
+    again = autotune_bsi(GRID, TILE, 2, reps=1, cache_path=str(cache),
+                         candidates=(("separable", "jnp"), ("ttli", "jnp")))
+    assert again == choice
+
+
+def test_candidate_without_memory_headroom_is_skipped(monkeypatch):
+    """A compiled step that would take more than STEP_MEMORY_SHARE of the
+    device is skipped (it may compile, then fail to load beside the rest of
+    the registration); with every candidate skipped the tuner says why."""
+    monkeypatch.setattr(autotune, "_device_bytes_limit", lambda dev: 1024)
+    autotune._MEM_CACHE.clear()
+    with pytest.raises(RuntimeError, match="needs .* GiB of the device"):
+        autotune_bsi(GRID, TILE, 2, reps=1, use_cache=False,
+                     candidates=(("separable", "jnp"), ("ttli", "jnp")))
+
+
+def test_non_memory_error_propagates_from_autotune_fused(monkeypatch):
+    from repro.core import ffd
+
+    monkeypatch.setenv("REPRO_AUTOTUNE_PALLAS", "1")
+
+    def broken(*a, **kw):
+        raise TypeError("fused kernel bug")
+
+    monkeypatch.setattr(ffd, "fused_warp_loss", broken)
+    base = autotune.BsiChoice("separable", "jnp", 0.0, "jnp")
+    autotune._MEM_CACHE.clear()
+    with pytest.raises(TypeError, match="fused kernel bug"):
+        autotune.autotune_fused(GRID, TILE, (8, 8, 8), base=base,
+                                similarity="ssd", reps=1, use_cache=False)
+
+
+def test_resolve_options_reports_skipped_candidates(tmp_path, monkeypatch):
+    import jax
+
+    from repro.core import RegistrationOptions
+    from repro.engine.autotune import resolve_options
+
+    oom = jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
+    _failing_interpolate(monkeypatch, "ttli", oom)
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "c.json"))
+    autotune._MEM_CACHE.clear()
+    opts = resolve_options(RegistrationOptions(
+        tile=TILE, impl="jnp", grad_impl="jnp", fused="off"), (9, 8, 7))
+    assert opts.mode != "ttli"
+    assert ("ttli/jnp/jnp", "RESOURCE_EXHAUSTED: out of memory") \
+        in opts.skipped

@@ -133,7 +133,8 @@ def test_collective_bytes_counted_inside_loops():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as PS
         from repro.launch.hlo_analysis import analyze_hlo
-        mesh = jax.make_mesh((2,), ("d",), devices=jax.devices()[:2])
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("d",))
         def f(x):
             def body(c, _):
                 s = jax.lax.with_sharding_constraint(c, PS("d", None))
@@ -141,8 +142,7 @@ def test_collective_bytes_counted_inside_loops():
             y, _ = jax.lax.scan(body, x, None, length=4)
             return y
         x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-        ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-        with ctx:
+        with jax.set_mesh(mesh):
             txt = jax.jit(f).lower(x).compile().as_text()
         s = analyze_hlo(txt)
         n = sum(s.collective_counts.values())

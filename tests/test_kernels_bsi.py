@@ -7,7 +7,7 @@ import pytest
 from repro.kernels import ops
 from repro.kernels.ref import bsi_ref
 
-KERNEL_MODES = ("tt", "ttli", "separable", "matmul")
+KERNEL_MODES = ops.PALLAS_MODES
 
 SHAPE_SWEEP = [
     # (grid points per axis, tile)
@@ -17,6 +17,8 @@ SHAPE_SWEEP = [
     ((11, 4, 6), (7, 7, 7)),     # paper's largest tile, non-cubic grid
     ((12, 12, 5), (6, 6, 6)),
     ((5, 13, 9), (4, 6, 5)),     # mixed tile
+    ((14, 5, 30), (5, 5, 5)),    # 11 x-tiles: padded up to whole x blocks
+    ((5, 30, 33), (2, 5, 5)),    # dense y, z past one (8, 128) plane tile
 ]
 
 
@@ -56,13 +58,23 @@ def test_kernel_channels(mode):
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
 
-@pytest.mark.parametrize("block_tiles", [(1, 1, 1), (2, 2, 2), (4, 2, 1)])
-def test_kernel_block_shapes(block_tiles):
+@pytest.mark.parametrize("mode", KERNEL_MODES)
+@pytest.mark.parametrize("block_tiles", [1, 2, 4])
+def test_kernel_block_shapes(block_tiles, mode):
     rng = np.random.default_rng(7)
     phi = jnp.asarray(rng.standard_normal((8, 8, 8, 3)), jnp.float32)
     ref = bsi_ref(phi, (5, 5, 5))
-    out = ops.bsi_pallas(phi, (5, 5, 5), mode="ttli", block_tiles=block_tiles)
+    out = ops.bsi_pallas(phi, (5, 5, 5), mode=mode, block_tiles=block_tiles)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
+
+
+@pytest.mark.parametrize("mode", sorted(ops.NO_KERNEL))
+def test_modes_without_kernel_refused_with_reason(mode):
+    """Forms with no Pallas kernel are refused up front, naming why — never
+    left to fail inside the TPU compiler at run time."""
+    phi = jnp.zeros((5, 5, 5, 3), jnp.float32)
+    with pytest.raises(ValueError, match=ops.NO_KERNEL[mode][:20]):
+        ops.bsi_pallas(phi, (3, 3, 3), mode=mode)
 
 
 def test_default_interpret_resolves_from_backend(monkeypatch):
@@ -81,26 +93,28 @@ def test_bsi_pallas_runs_without_interpret_flag():
     # on the CPU test backend the default must resolve to interpret mode
     rng = np.random.default_rng(0)
     phi = jnp.asarray(rng.standard_normal((6, 6, 6, 3)), jnp.float32)
-    out = ops.bsi_pallas(phi, (4, 4, 4), mode="ttli")
+    out = ops.bsi_pallas(phi, (4, 4, 4), mode="separable")
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(bsi_ref(phi, (4, 4, 4))), atol=3e-6)
 
 
 def test_pick_block_tiles_respects_budget():
-    bt = ops.pick_block_tiles((64, 64, 64), (7, 7, 7), 3, 4, budget=1 * 2**20)
-    dx, dy, dz = 7, 7, 7
-    out_bytes = bt[0] * dx * bt[1] * dy * bt[2] * dz * 3 * 4
-    assert out_bytes < 1 * 2**20
+    """The x block shrinks until the cell's VMEM model fits the budget; at
+    phantom1's grid (103 x 46 x 77 tiles) the default budget takes 8 tiles."""
+    tiles, tile = (103, 46, 77), (5, 5, 5)
+    assert ops.pick_block_tiles(tiles, tile) == 8
+    small = 20 * 2**20
+    bt = ops.pick_block_tiles(tiles, tile, budget=small)
+    assert 1 <= bt < 8
+    assert ops._block_bytes(bt, tiles, tile, 4, adjoint=False) <= small
+    assert ops._block_bytes(bt + 1, tiles, tile, 4, adjoint=False) > small
 
 
 def test_pick_block_tiles_clamps_to_tiny_grids():
-    """num_tiles is honoured: a grid smaller than the default block must not
-    budget for (and pad up to) blocks larger than the whole grid."""
-    assert ops.pick_block_tiles((2, 1, 3), (5, 5, 5), 3, 4) == (2, 1, 3)
-    # clamping also frees budget: a tiny grid keeps its axes un-halved even
-    # under a budget that would shrink the default 4^3 block
-    bt = ops.pick_block_tiles((1, 1, 64), (7, 7, 7), 3, 4, budget=2**20)
-    assert bt[0] == 1 and bt[1] == 1
+    """num_tiles is honoured: a grid with fewer x tiles than the default
+    block never pads up to a larger block."""
+    assert ops.pick_block_tiles((2, 1, 3), (5, 5, 5)) == 2
+    assert ops.pick_block_ctrl((1, 1, 64), (7, 7, 7)) == 1
     # and the padded kernel path agrees with the oracle on such grids
     rng = np.random.default_rng(11)
     phi = jnp.asarray(rng.standard_normal((5, 4, 6, 3)), jnp.float32)

@@ -157,6 +157,20 @@ class TestFailureModes:
         sched.run_until_idle()
         assert h.result() is not None
 
+    def test_lane_state_leaves_own_their_buffers(self):
+        """The splice and chunk programs donate the lane state on
+        accelerators, and a buffer shared by two leaves cannot be donated
+        twice — so every freshly allocated leaf owns its buffer."""
+        import jax
+
+        sched = RegistrationScheduler(OPTS, lanes=2)
+        bucket = sched._bucket_for(SHAPE)
+        stage = bucket.stages[0]
+        sched._alloc(bucket, stage, bucket.lvl_shapes[0])
+        leaves = jax.tree.leaves((stage.state, stage.fixed, stage.moving))
+        ptrs = [x.unsafe_buffer_pointer() for x in leaves]
+        assert len(set(ptrs)) == len(ptrs)
+
     def test_constructor_validation(self):
         with pytest.raises(TypeError, match="RegistrationOptions"):
             RegistrationScheduler({"iters": 3})

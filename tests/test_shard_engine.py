@@ -6,6 +6,7 @@ the plain CI tests job, 8 in the ``multi-device`` job (which exports
 test pins the 8-device layout so the acceptance path is exercised even in a
 single-device run.
 """
+import functools
 import os
 import subprocess
 import sys
@@ -21,6 +22,7 @@ from jax.sharding import PartitionSpec as PS
 from repro.data.volumes import make_pair
 from repro.distributed.sharding import REGISTRATION_RULES
 from repro.engine import make_registration_mesh, register_batch
+from repro.engine.batch import ffd_pipeline
 from repro.engine.shard import (GRID_AXES, LOSS_AXES, VOLUME_AXES,
                                 batch_mask, batch_multiple,
                                 compile_sharded_batch, pad_batch)
@@ -138,8 +140,10 @@ def test_compiled_sharded_outputs_stay_distributed():
     """out_shardings keep results on the mesh (no gather to one device)."""
     mesh = make_registration_mesh()
     n = mesh.shape["data"]
-    fn = compile_sharded_batch(mesh, TILE, 1, 2, 0.5, 5e-3,
-                               "separable", "jnp", "ssd")
+    pair = functools.partial(ffd_pipeline, tile=TILE, levels=1, iters=2,
+                             lr=0.5, bending_weight=5e-3, mode="separable",
+                             impl="jnp")
+    fn = compile_sharded_batch(jax.vmap(pair), mesh)
     F, M = _stack(1)
     F = jnp.concatenate([F] * n, axis=0)
     M = jnp.concatenate([M] * n, axis=0)

@@ -125,7 +125,8 @@ def test_pipeline_parallel_multistage_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import pipeline_apply
-        mesh = jax.make_mesh((4,), ("pp",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pp",))
         rng = np.random.default_rng(0)
         w = jnp.asarray(rng.standard_normal((4, 8, 8)) * 0.3, jnp.float32)
         x = jnp.asarray(rng.standard_normal((8, 8)), jnp.float32)
