@@ -111,7 +111,7 @@ def run(shape=(48, 40, 36), iters=25, affine_iters=30, multimodal=True):
                 continue
             res[(mode, impl)] = ffd_register(
                 fixed, moving, tile=TILE, levels=2, iters=iters,
-                mode=mode, impl=impl, measure_bsi_time=True,
+                mode=mode, impl=impl,
             )
         # fused level step, forced on: the dense field and warped volume
         # never hit HBM (on CPU hosts the kernel runs in interpret mode —
@@ -130,19 +130,16 @@ def run(shape=(48, 40, 36), iters=25, affine_iters=30, multimodal=True):
             (f"registration/{name}/ffd_gather",
              round(base.seconds * 1e6, 0),
              f"mae={float(metrics.mae(base.warped, fixed)):.4f}"
-             f"|ssim={float(metrics.ssim(base.warped, fixed)):.4f}"
-             f"|bsi_s={base.bsi_seconds:.3f}"),
+             f"|ssim={float(metrics.ssim(base.warped, fixed)):.4f}"),
             (f"registration/{name}/ffd_separable",
              round(opt.seconds * 1e6, 0),
              f"mae={float(metrics.mae(opt.warped, fixed)):.4f}"
              f"|ssim={float(metrics.ssim(opt.warped, fixed)):.4f}"
-             f"|bsi_s={opt.bsi_seconds:.3f}"
              f"|reg_speedup=x{base.seconds / max(opt.seconds, 1e-9):.2f}"),
             (f"registration/{name}/ffd_matmul",
              round(mm.seconds * 1e6, 0),
              f"mae={float(metrics.mae(mm.warped, fixed)):.4f}"
              f"|ssim={float(metrics.ssim(mm.warped, fixed)):.4f}"
-             f"|bsi_s={mm.bsi_seconds:.3f}"
              f"|reg_speedup=x{base.seconds / max(mm.seconds, 1e-9):.2f}"),
             (f"registration/{name}/ffd_auto",
              round(auto.seconds * 1e6, 0),

@@ -72,9 +72,10 @@ def dense_field(phi, tile, vol_shape, *, mode="separable", impl="jnp",
     — the engine's ``"auto"`` therefore never picks it under a reduced
     ``compute_dtype``).
     """
-    full = interpolate(phi, tile, mode=mode, impl=impl, grad_impl=grad_impl,
-                       dtype=compute_dtype)
-    return full[: vol_shape[0], : vol_shape[1], : vol_shape[2]]
+    with jax.named_scope("bsi"):
+        full = interpolate(phi, tile, mode=mode, impl=impl,
+                           grad_impl=grad_impl, dtype=compute_dtype)
+        return full[: vol_shape[0], : vol_shape[1], : vol_shape[2]]
 
 
 def fused_warp_loss(phi, moving, fixed, tile, *, similarity="ssd",
@@ -188,16 +189,17 @@ def warp_volume(moving, disp, compute_dtype=None):
     represent integers above 256, so a bf16 identity grid would shift
     sampling positions by whole voxels on paper-scale (>256-voxel) volumes.
     """
-    coord_dtype = jnp.promote_types(disp.dtype, jnp.float32)
-    if compute_dtype is not None:
-        moving = jnp.asarray(moving, compute_dtype)
-    disp = jnp.asarray(disp, coord_dtype)
-    X, Y, Z = moving.shape
-    xs = jnp.arange(X, dtype=coord_dtype)
-    ys = jnp.arange(Y, dtype=coord_dtype)
-    zs = jnp.arange(Z, dtype=coord_dtype)
-    ident = jnp.stack(jnp.meshgrid(xs, ys, zs, indexing="ij"), axis=-1)
-    return trilinear_sample(moving, ident + disp)
+    with jax.named_scope("warp"):
+        coord_dtype = jnp.promote_types(disp.dtype, jnp.float32)
+        if compute_dtype is not None:
+            moving = jnp.asarray(moving, compute_dtype)
+        disp = jnp.asarray(disp, coord_dtype)
+        X, Y, Z = moving.shape
+        xs = jnp.arange(X, dtype=coord_dtype)
+        ys = jnp.arange(Y, dtype=coord_dtype)
+        zs = jnp.arange(Z, dtype=coord_dtype)
+        ident = jnp.stack(jnp.meshgrid(xs, ys, zs, indexing="ij"), axis=-1)
+        return trilinear_sample(moving, ident + disp)
 
 
 def bending_energy(phi):

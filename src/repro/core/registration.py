@@ -30,13 +30,14 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import ffd
 from repro.core.ffd import downsample2  # re-exported (seed API)
 from repro.core.options import (UNSET, RegistrationOptions,
                                 merge_legacy_options)
 from repro.engine.autotune import resolve_options
-from repro.engine.batch import ffd_level_objective
+from repro.engine.batch import compile_finish, ffd_level_objective
 from repro.engine.loop import make_adam_runner
 
 __all__ = ["RegistrationResult", "affine_register", "ffd_register", "downsample2"]
@@ -52,7 +53,6 @@ class RegistrationResult:
     params: Any              # affine matrix or control grid pytree per level
     losses: list             # loss trace
     seconds: float           # wall time
-    bsi_seconds: float = 0.0 # time inside BSI (paper Figs. 8-9 breakdown)
     steps: Any = None        # optimiser steps per level when stop= was set
 
 
@@ -189,7 +189,6 @@ def ffd_register(
     regularizer=UNSET,
     stop=UNSET,
     optimizer=UNSET,
-    measure_bsi_time=False,
 ):
     """Multi-resolution FFD registration (NiftyReg workflow, paper §6).
 
@@ -237,57 +236,38 @@ def ffd_register(
              grad_impl=grad_impl, compute_dtype=compute_dtype,
              similarity=similarity, transform=transform,
              regularizer=regularizer, stop=stop, optimizer=optimizer))
-    opts = resolve_options(opts, fixed.shape)  # autotune + canonicalise
+    with TraceAnnotation("repro.resolve"):
+        opts = resolve_options(opts, fixed.shape)  # autotune + canonicalise
     tile, stop = opts.tile, opts.stop
 
-    pyramid = [(fixed, moving)]
-    for _ in range(opts.levels - 1):
-        f, m = pyramid[-1]
-        pyramid.append((downsample2(f), downsample2(m)))
-    pyramid = pyramid[::-1]  # coarse -> fine
+    with TraceAnnotation("repro.pyramid"):
+        pyramid = [(fixed, moving)]
+        for _ in range(opts.levels - 1):
+            f, m = pyramid[-1]
+            pyramid.append((downsample2(f), downsample2(m)))
+        pyramid = pyramid[::-1]  # coarse -> fine
 
-    bsi_fn = functools.partial(ffd.dense_field, mode=opts.mode,
-                               impl=opts.impl)
     phi = None
     losses = []
     steps = [] if stop is not None else None
-    bsi_seconds = 0.0
     t0 = time.perf_counter()
 
     for level, (f, m) in enumerate(pyramid):
-        gshape = ffd.grid_shape_for_volume(f.shape, tile)
-        if phi is None:
-            phi = jnp.zeros(gshape + (3,), jnp.float32)
-        else:
-            phi = ffd.upsample_grid(phi, gshape)
-
-        runner = _ffd_level_runner(f.shape, opts)
-        out = runner(phi, f, m)
+        with TraceAnnotation("repro.level", level=level):
+            gshape = ffd.grid_shape_for_volume(f.shape, tile)
+            if phi is None:
+                phi = jnp.zeros(gshape + (3,), jnp.float32)
+            else:
+                phi = ffd.upsample_grid(phi, gshape)
+            out = _ffd_level_runner(f.shape, opts)(phi, f, m)
         phi, trace = out[:2]
-        if stop is not None:
-            steps.append(int(out[2]))
-        phi.block_until_ready()
-        losses.append(float(trace[-1]))
+        with TraceAnnotation("repro.sync"):
+            if stop is not None:
+                steps.append(int(out[2]))
+            phi.block_until_ready()
+            losses.append(float(trace[-1]))
 
-        if measure_bsi_time and level == len(pyramid) - 1:
-            # Isolate the BSI fraction the paper optimises (Figs. 8-9).
-            dense = jax.jit(lambda p: bsi_fn(p, tile, f.shape))
-            dense(phi).block_until_ready()  # compile
-            reps = 3
-            t1 = time.perf_counter()
-            for _ in range(reps):
-                dense(phi).block_until_ready()
-            # 2 BSI evaluations per optimisation step (forward + grad),
-            # scaled by the steps this level actually ran.
-            ran = steps[-1] if stop is not None else opts.iters
-            bsi_seconds = (time.perf_counter() - t1) / reps * ran * 2
-
-    from repro.core.transform import dense_displacement
-
-    disp = dense_displacement(opts.transform, phi, tile, fixed.shape,
-                              mode=opts.mode, impl=opts.impl)
-    warped = ffd.warp_volume(moving, disp)
-    return RegistrationResult(
-        warped, phi, losses, time.perf_counter() - t0, bsi_seconds,
-        steps=steps
-    )
+    with TraceAnnotation("repro.final"):
+        warped = compile_finish(fixed.shape, opts)(phi, moving)
+    return RegistrationResult(warped, phi, losses, time.perf_counter() - t0,
+                              steps=steps)

@@ -25,6 +25,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import ffd
 from repro.core.options import UNSET, merge_legacy_options
@@ -122,7 +123,9 @@ def ffd_level_loss(f, mov, *, tile, bending_weight, mode, impl,
         # its trade-off against the fp32 regulariser) in bf16
         warped = warped.astype(jnp.float32)
         fixed32 = f.astype(jnp.float32)
-        return sim(warped, fixed32) + reg(p)
+        with jax.named_scope("similarity"):
+            simloss = sim(warped, fixed32)
+        return simloss + reg(p)
 
     return loss_fn
 
@@ -360,10 +363,12 @@ def register_batch(fixed, moving, *, options=None, tile=UNSET, levels=UNSET,
     fn = _compiled_batch(fixed.shape[1:], opts, mesh)
     compiled = _compiled_batch.cache_info().misses > misses
     stop = opts.stop
-    out = fn(fixed, moving)
+    with TraceAnnotation("repro.level"):
+        out = fn(fixed, moving)
     warped, phi, losses = out[:3]
     steps = out[3] if stop is not None else None
-    jax.block_until_ready(warped)
+    with TraceAnnotation("repro.sync"):
+        jax.block_until_ready(warped)
     seconds = time.perf_counter() - t0
     if mesh is not None:  # strip the pad rows (see engine.shard.pad_batch)
         warped, phi, losses = warped[:b], phi[:b], losses[:b]

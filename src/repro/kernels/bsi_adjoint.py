@@ -113,4 +113,5 @@ def bsi_adjoint_pallas_planes(g, ayt, az, *, dx, bt, form, interpret):
         scratch_shapes=scratch,
         compiler_params=common.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name=f"bsi_adjoint_{form}",
     )(ayt, az, g)

@@ -61,4 +61,5 @@ def bsi_matmul_pallas(phi, ay, azt, *, dx, bt, interpret):
         out_shape=jax.ShapeDtypeStruct((c, nb * bt * dx, y, z), phi.dtype),
         compiler_params=common.compiler_params("parallel", "parallel"),
         interpret=interpret,
+        name="bsi_matmul",
     )(ay, azt, phi)

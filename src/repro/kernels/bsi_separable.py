@@ -73,4 +73,5 @@ def bsi_separable_pallas(phi, ay, azt, *, dx, bt, interpret):
         scratch_shapes=[pltpu.VMEM((bt + 3, y, z), jnp.float32)],
         compiler_params=common.compiler_params("parallel", "parallel"),
         interpret=interpret,
+        name="bsi_separable",
     )(ay, azt, phi)

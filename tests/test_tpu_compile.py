@@ -8,6 +8,8 @@ grid (paper Table 2, tile 5) and let the real compiler judge.  The topology
 is described inside a module fixture only: describing it loads the TPU
 library, which one process at a time may hold.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -111,3 +113,119 @@ def test_fused_supported_is_truthful_on_tpu(one_chip, compiled_kernels,
             lambda p, m, f: ops.fused_similarity_loss(p, m, f, TILE,
                                                       sim_spec=spec),
             _spec(grid, one_chip), _spec(vol, one_chip), _spec(vol, one_chip))
+
+
+# --- stage scopes: what the benchmark's trace reader buckets by --------------
+
+SMALL = (40, 36, 32)
+SMALL_GRID = ffd.grid_shape_for_volume(SMALL, TILE) + (3,)
+_SCOPE = re.compile(r"(?:[\w-]+\()*([\w-]+)\)*")
+
+
+def _scopes(op_name):
+    """Scope names in an ``op_name``'s components, with the
+    transformations around them (``transpose(jvp(bsi))``) taken off."""
+    return {m.group(1) for part in op_name.split("/")
+            for m in [_SCOPE.fullmatch(part)] if m}
+
+
+def _ops(txt):
+    """``(instruction, op_name, body of the computation it calls)`` for each
+    instruction of the compiled text outside fused computations."""
+    bodies, current = {}, None
+    for line in txt.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(.*\{$", line)
+        if head:
+            current = head.group(1)
+            bodies[current] = []
+        elif current is not None:
+            bodies[current].append(line)
+    out = []
+    for comp, lines in bodies.items():
+        if comp.startswith("fused_computation"):
+            continue
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.-]+) = ", line)
+            if not m:
+                continue
+            called = re.search(r"calls=%([\w.-]+)", line)
+            op = re.search(r'op_name="([^"]*)"', line)
+            body = "\n".join(bodies.get(called.group(1), [])) if called else ""
+            out.append((line, op.group(1) if op else "", body))
+    return out
+
+
+def _assert_stages(txt, nmi_exp):
+    ops = _ops(txt)
+    gathers = [(l, op) for l, op, body in ops
+               if " fusion(" in l and " gather(" in body]
+    kernels = [(l, op) for l, op, _ in ops if "tpu_custom_call" in l]
+    assert gathers and kernels
+    assert all("warp" in _scopes(op) for _, op in gathers), gathers
+    assert all("bsi" in _scopes(op) for _, op in kernels), kernels
+    if nmi_exp:
+        exps = [(l, op) for l, op, body in ops
+                if " fusion(" in l and " exponential(" in body]
+        assert exps
+        assert all("similarity" in _scopes(op) for _, op in exps), exps
+
+
+def _cell_options(sim, grad_impl):
+    from repro.core.similarity import nmi
+
+    return resolve_options(RegistrationOptions(
+        tile=TILE, levels=1, iters=2, mode="separable", impl="pallas",
+        grad_impl=grad_impl, similarity=nmi(64) if sim == "nmi" else sim,
+        fused="off"), SMALL)
+
+
+@pytest.mark.parametrize("grad_impl", ["pallas", "jnp"])
+@pytest.mark.parametrize("sim", ["ssd", "nmi"])
+def test_level_step_ops_carry_their_stage(one_chip, compiled_kernels, sim,
+                                          grad_impl):
+    """The level step of the benchmark cells' autotuned forms
+    (``separable/pallas/pallas`` and ``separable/pallas/jnp``), compiled
+    for the chip: every gather fusion lies in the ``warp`` scope, every
+    Pallas kernel in ``bsi``, and NMI's exponentials in ``similarity``."""
+    from repro.core.registration import _ffd_level_runner
+
+    opts = _cell_options(sim, grad_impl)
+    txt = (_ffd_level_runner(SMALL, opts)
+           .lower(_spec(SMALL_GRID, one_chip), _spec(SMALL, one_chip),
+                  _spec(SMALL, one_chip)).compile().as_text())
+    _assert_stages(txt, nmi_exp=sim == "nmi")
+
+
+@pytest.mark.parametrize("sim", ["ssd", "nmi"])
+def test_final_warp_is_one_program_with_stages(one_chip, compiled_kernels,
+                                               sim):
+    """``ffd_register``'s final displacement and warp compile as one
+    program whose gathers lie in ``warp`` and whose kernel lies in ``bsi``."""
+    from repro.engine.batch import compile_finish
+
+    opts = _cell_options(sim, "pallas")
+    txt = (compile_finish(SMALL, opts)
+           .lower(_spec(SMALL_GRID, one_chip), _spec(SMALL, one_chip))
+           .compile().as_text())
+    _assert_stages(txt, nmi_exp=False)
+
+
+def test_kernel_names_differ_by_form(one_chip, compiled_kernels):
+    """Each Pallas kernel compiles to a custom call named for its form; only
+    the adjoints' names start with ``bsi_adjoint``."""
+    dense = tuple((n - 3) * d for n, d in zip(SMALL_GRID, TILE)) + (3,)
+    names = {}
+    for mode in ops.PALLAS_MODES:
+        txt = _kernel_text(lambda p: ops.bsi_pallas(p, TILE, mode=mode),
+                           _spec(SMALL_GRID, one_chip))
+        names[mode] = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(.*"
+                                 r"tpu_custom_call", txt)
+    for form in ("separable", "matmul"):
+        txt = _kernel_text(
+            lambda g: ops.bsi_adjoint_pallas(g, TILE, form=form),
+            _spec(dense, one_chip))
+        names["adjoint_" + form] = re.findall(
+            r"%(\w+)\.\d+ = \S+ custom-call\(.*tpu_custom_call", txt)
+    assert names == {"separable": ["bsi_separable"], "matmul": ["bsi_matmul"],
+                     "adjoint_separable": ["bsi_adjoint_separable"],
+                     "adjoint_matmul": ["bsi_adjoint_matmul"]}
