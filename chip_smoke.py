@@ -21,7 +21,9 @@ d. ``RegistrationScheduler`` answering three requests at porcine1 (fewer
 e. (``--four-chips``) ``register_batch(mesh=)`` of four porcine1 pairs on a
    four-device mesh against one-device runs of each pair, with the Pallas
    forward and adjoint pinned (phase b shows what ``"auto"`` picks; the
-   point here is the kernels under ``shard_map``).
+   point here is the kernels under ``shard_map``), and
+   ``RegistrationScheduler(mesh=)`` serving the same four pairs, one lane
+   per chip, against ``register_batch``'s results.
 
 The last line of standard output is ``{"ok": true, "device": {...}}`` and
 is printed only when every phase passed.  The persistent compilation cache
@@ -252,7 +254,8 @@ def phase_serve(fixed, options):
 
 
 def phase_four_chips(iters):
-    """e. register_batch over a 4-device mesh vs one-device runs."""
+    """e. register_batch and the scheduler over a 4-device mesh vs
+    one-device runs."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -260,6 +263,7 @@ def phase_four_chips(iters):
     from repro.core import RegistrationOptions
     from repro.data.volumes import make_phantom
     from repro.engine import make_registration_mesh, register_batch
+    from repro.engine.serve import RegistrationScheduler
 
     n = len(jax.devices())
     check(n == 4, f"--four-chips needs 4 devices, JAX sees {n}")
@@ -293,6 +297,18 @@ def phase_four_chips(iters):
         sharded.losses))))
     check(diffs["warped"][0] <= WARPED_ATOL and lrel <= LOSS_RTOL,
           f"mesh and one-device results differ: {diffs}")
+
+    sched = RegistrationScheduler(options, lanes=n, mesh=mesh)
+    handles = [sched.submit(F[i], M[i]) for i in range(n)]
+    t0 = time.perf_counter()
+    sched.run_until_idle()
+    served = np.stack([np.asarray(h.result().warped) for h in handles])
+    diff = float(np.max(np.abs(served - np.asarray(sharded.warped))))
+    log("e", f"RegistrationScheduler, {n} lanes on the mesh: "
+             f"{time.perf_counter() - t0:.3f} s (compiles included), "
+             f"warped max|diff| against register_batch {diff:.3e}")
+    check(diff <= WARPED_ATOL,
+          f"the scheduler on the mesh differs from register_batch: {diff}")
 
 
 def main(argv=None):

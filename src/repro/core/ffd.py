@@ -188,18 +188,64 @@ def warp_volume(moving, disp, compute_dtype=None):
     volume's dtype).  Sampling *coordinates* always stay fp32: bf16 cannot
     represent integers above 256, so a bf16 identity grid would shift
     sampling positions by whole voxels on paper-scale (>256-voxel) volumes.
+
+    Where ``kernels.ops.warp_supported`` admits the volume (a TPU, float32,
+    within the kernel's VMEM budget) the warp runs as the Pallas kernel
+    ``warp_trilinear`` (:func:`_warp_kernel`); otherwise as
+    :func:`trilinear_sample`'s XLA gathers.  Both give the same values.
     """
+    from repro.kernels import ops
+
     with jax.named_scope("warp"):
         coord_dtype = jnp.promote_types(disp.dtype, jnp.float32)
         if compute_dtype is not None:
             moving = jnp.asarray(moving, compute_dtype)
         disp = jnp.asarray(disp, coord_dtype)
-        X, Y, Z = moving.shape
-        xs = jnp.arange(X, dtype=coord_dtype)
-        ys = jnp.arange(Y, dtype=coord_dtype)
-        zs = jnp.arange(Z, dtype=coord_dtype)
-        ident = jnp.stack(jnp.meshgrid(xs, ys, zs, indexing="ij"), axis=-1)
-        return trilinear_sample(moving, ident + disp)
+        if ops.warp_supported(moving.shape, moving.dtype):
+            return _warp_kernel(moving, disp)
+        return _warp_gather(moving, disp)
+
+
+def _warp_gather(moving, disp):
+    """``warp_volume``'s XLA form: an identity grid and eight gathers."""
+    X, Y, Z = moving.shape
+    dt = disp.dtype
+    ident = jnp.stack(jnp.meshgrid(jnp.arange(X, dtype=dt),
+                                   jnp.arange(Y, dtype=dt),
+                                   jnp.arange(Z, dtype=dt), indexing="ij"),
+                      axis=-1)
+    return trilinear_sample(moving, ident + disp)
+
+
+@jax.custom_jvp
+def _warp_kernel(moving, disp):
+    """The warp kernel, differentiable.
+
+    Its derivative with respect to ``disp`` is the kernel's own partials
+    (computed in the same pass), so reverse mode multiplies the cotangent by
+    them and forward mode (Gauss-Newton's ``jax.linearize``) the tangent.
+    With respect to ``moving`` the warp is linear: the XLA form of the
+    tangent, whose transpose is the gathers' scatter; nothing computes it
+    unless ``moving`` is differentiated.
+    """
+    from repro.kernels import ops
+
+    return ops.warp_trilinear(moving, disp)
+
+
+@functools.partial(_warp_kernel.defjvp, symbolic_zeros=True)
+def _warp_kernel_jvp(primals, tangents):
+    from repro.kernels import ops
+
+    moving, disp = primals
+    dmoving, ddisp = tangents
+    warped, partials = ops.warp_trilinear(moving, disp, partials=True)
+    tangent = jnp.zeros_like(warped)
+    if not isinstance(ddisp, jax.custom_derivatives.SymbolicZero):
+        tangent = jnp.sum(partials * ddisp, axis=-1)
+    if not isinstance(dmoving, jax.custom_derivatives.SymbolicZero):
+        tangent = tangent + _warp_gather(dmoving, disp)
+    return warped, tangent
 
 
 def bending_energy(phi):

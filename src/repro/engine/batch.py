@@ -441,7 +441,7 @@ def _lane_init(phi, f, m, *, options):
 
 
 @functools.lru_cache(maxsize=128)
-def compile_level_splice(lvl_shape, options):
+def compile_level_splice(lvl_shape, options, mesh=None):
     """Jitted lane admission: init one pair AND scatter it into lane ``i``.
 
     ``(state, F, M, i, phi0, f, m) -> (state, F, M)`` — the fused form of
@@ -450,20 +450,35 @@ def compile_level_splice(lvl_shape, options):
     dispatches (profiled at ~10ms/admission on CPU, a third of the serving
     wall-clock at small volume sizes).  The stacked operands are donated on
     accelerator backends — the scheduler threads them through every call.
+    With ``mesh`` the stacked operands are lane-sharded and the program runs
+    per device (``engine.shard.compile_lane_program``): every device inits
+    the pair, and the one holding lane ``i`` keeps it.
     """
     del lvl_shape  # cache key only
 
     def splice(state, F, M, i, phi, f, m):
         lane = _lane_init(phi, f, m, options=options)
-        state = jax.tree.map(lambda a, s: a.at[i].set(s), state, lane)
-        return state, F.at[i].set(f), M.at[i].set(m)
+        if mesh is not None:
+            from repro.engine.shard import local_lane
+
+            i = local_lane(i, F.shape[0], mesh)
+
+        def put(a, s):
+            return a.at[i].set(s, mode="drop")
+
+        return jax.tree.map(put, state, lane), put(F, f), put(M, m)
 
     donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
-    return jax.jit(splice, donate_argnums=donate)
+    if mesh is None:
+        return jax.jit(splice, donate_argnums=donate)
+    from repro.engine.shard import compile_lane_program
+
+    return compile_lane_program(splice, mesh, (True,) * 3 + (False,) * 4,
+                                donate)
 
 
 @functools.lru_cache(maxsize=128)
-def compile_level_chunk(lvl_shape, options, chunk):
+def compile_level_chunk(lvl_shape, options, chunk, mesh=None):
     """Jitted ``(state, fixed, moving) -> state``: one chunk of a level.
 
     Runs ``chunk`` masked optimiser steps (``options.optimizer``) over a
@@ -484,7 +499,8 @@ def compile_level_chunk(lvl_shape, options, chunk):
     With ``options.stop`` unset the masking reduces to the fixed-``iters``
     budget and ``tol=-inf`` makes every accepted step "improve", so
     ``best_p`` tracks the current params and the result matches
-    ``optimize_scan``.
+    ``optimize_scan``.  With ``mesh`` the lanes are sharded and each device
+    steps its own (``engine.shard.compile_lane_program``).
     """
     del lvl_shape  # cache key only
     o = options
@@ -511,7 +527,11 @@ def compile_level_chunk(lvl_shape, options, chunk):
         return s
 
     donate = (0,) if jax.default_backend() != "cpu" else ()
-    return jax.jit(jax.vmap(lane), donate_argnums=donate)
+    if mesh is None:
+        return jax.jit(jax.vmap(lane), donate_argnums=donate)
+    from repro.engine.shard import compile_lane_program
+
+    return compile_lane_program(jax.vmap(lane), mesh, (True,) * 3, donate)
 
 
 @functools.lru_cache(maxsize=64)

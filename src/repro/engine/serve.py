@@ -188,6 +188,30 @@ def _upsample_fn(gshape):
     return jax.jit(lambda p: ffd.upsample_grid(p, gshape))
 
 
+def lane_arrays(lanes, lvl_shape, options):
+    """``(state, fixed, moving)``: one stage's stacked lane arrays at
+    ``lvl_shape``, every lane inactive — the operands of
+    ``engine.batch.compile_level_chunk`` and ``compile_level_splice``."""
+    W = int(lanes)
+    grid = ffd.grid_shape_for_volume(lvl_shape, options.tile) + (3,)
+    # a fresh buffer per leaf: the splice and chunk programs donate the
+    # state, and one buffer behind two leaves cannot be donated twice
+    zg = functools.partial(jnp.zeros, (W,) + grid, jnp.float32)
+    zi = functools.partial(jnp.zeros, (W,), jnp.int32)
+    zf = functools.partial(jnp.zeros, (W,), jnp.float32)
+    # the optimiser state's lane template comes from the registry, so a
+    # new optimiser's lanes allocate (and shard) without touching the
+    # scheduler: every leaf is stacked to a leading (W, ...) lane axis
+    opt = jax.tree.map(
+        lambda a: jnp.zeros((W,) + a.shape, a.dtype),
+        init_state(options.optimizer, jnp.zeros(grid, jnp.float32)))
+    state = dict(phi=zg(), opt=opt, g=zg(), best_p=zg(), k=zi(),
+                 since=zi(), best=zf(), loss=zf(),
+                 active=jnp.zeros((W,), jnp.bool_))
+    vol = (W,) + tuple(lvl_shape)
+    return (state, jnp.zeros(vol, jnp.float32), jnp.zeros(vol, jnp.float32))
+
+
 def _host_live(k, since, stop, iters):
     if stop is None:
         return k < iters
@@ -214,8 +238,10 @@ class RegistrationScheduler:
         :class:`RegistrationTimeout` at the next round boundary (a round's
         device work is never interrupted mid-chunk).
       mesh: optional ``jax.sharding.Mesh`` — lane arrays shard batch-over-
-        data (``engine.shard.lane_sharding``), one chunk program driving
-        all devices.
+        data (``engine.shard.lane_sharding``); the chunk and splice programs
+        run under ``shard_map``, each device on its own lanes
+        (``engine.shard.compile_lane_program``), and a pair's final warp on
+        one device.
       clock: injectable monotonic-seconds source (tests use a fake clock to
         exercise deadlines deterministically).
     """
@@ -326,7 +352,7 @@ class RegistrationScheduler:
                 if key not in self._programs:
                     self._programs.add(key)
                     self.stats.compiles += 1
-                fn = compile_level_chunk(*key)
+                fn = compile_level_chunk(*key, self.mesh)
                 stage.state = fn(stage.state, stage.fixed, stage.moving)
                 self.stats.chunks += 1
                 ran.append(stage)
@@ -373,27 +399,9 @@ class RegistrationScheduler:
 
     def _alloc(self, bucket, stage, lvl_shape):
         """Allocate the stage's stacked lane arrays (all lanes inactive)."""
-        W = self.lanes
-        gshape = ffd.grid_shape_for_volume(lvl_shape, bucket.options.tile)
-        grid = gshape + (3,)
-        # a fresh buffer per leaf: the splice and chunk programs donate the
-        # state, and one buffer behind two leaves cannot be donated twice
-        zg = functools.partial(jnp.zeros, (W,) + grid, jnp.float32)
-        zi = functools.partial(jnp.zeros, (W,), jnp.int32)
-        zf = functools.partial(jnp.zeros, (W,), jnp.float32)
-        # the optimiser state's lane template comes from the registry, so a
-        # new optimiser's lanes allocate (and shard) without touching the
-        # scheduler: every leaf is stacked to a leading (W, ...) lane axis
-        opt = jax.tree.map(
-            lambda a: jnp.zeros((W,) + a.shape, a.dtype),
-            init_state(bucket.options.optimizer, jnp.zeros(grid,
-                                                           jnp.float32)))
-        state = dict(phi=zg(), opt=opt, g=zg(), best_p=zg(), k=zi(),
-                     since=zi(), best=zf(), loss=zf(),
-                     active=jnp.zeros((W,), jnp.bool_))
-        stage.fixed = jnp.zeros((W,) + lvl_shape, jnp.float32)
-        stage.moving = jnp.zeros((W,) + lvl_shape, jnp.float32)
-        stage.lanes = [None] * W
+        state, stage.fixed, stage.moving = lane_arrays(
+            self.lanes, lvl_shape, bucket.options)
+        stage.lanes = [None] * self.lanes
         if self.mesh is not None:
             from repro.engine.shard import lane_sharding
 
@@ -407,7 +415,7 @@ class RegistrationScheduler:
         if not stage.queue:
             return
         lvl_shape = bucket.lvl_shapes[stage.level]
-        splice = compile_level_splice(lvl_shape, bucket.options)
+        splice = compile_level_splice(lvl_shape, bucket.options, self.mesh)
         mid_flight = stage.any_active()
         if stage.lanes is None:
             self._alloc(bucket, stage, lvl_shape)
@@ -465,8 +473,13 @@ class RegistrationScheduler:
         return done
 
     def _finish(self, bucket, req):
+        phi = req.phi
+        if self.mesh is not None:
+            # one pair's program runs on one device: a Pallas kernel in it
+            # cannot be partitioned over the mesh
+            phi = jax.device_put(phi, next(iter(req.moving.devices())))
         warped = compile_finish(bucket.vol_shape, bucket.options)(
-            req.phi, req.moving)
+            phi, req.moving)
         handle = req.handle
         handle._result = ServeResult(
             warped=warped, params=req.phi, losses=req.losses,

@@ -29,7 +29,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import AxisType, NamedSharding
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
 
 from repro.distributed.sharding import REGISTRATION_RULES
 
@@ -42,6 +42,8 @@ __all__ = [
     "pad_batch",
     "batch_mask",
     "lane_sharding",
+    "local_lane",
+    "compile_lane_program",
     "compile_sharded_batch",
 ]
 
@@ -128,6 +130,39 @@ def lane_sharding(mesh):
         ("batch",)))
 
 
+def local_lane(i, width, mesh):
+    """Lane ``i`` of the whole lane array as an index into this device's
+    ``width`` lanes, inside a :func:`compile_lane_program` body; ``width``
+    (past the end: an update with ``mode="drop"`` leaves the block as it
+    is) where another device holds lane ``i``."""
+    axes = lane_sharding(mesh).spec[0]
+    if axes is None:
+        return i
+    j = i - jax.lax.axis_index(axes) * width
+    return jnp.where((j >= 0) & (j < width), j, width)
+
+
+def compile_lane_program(fn, mesh, lanes, donate_argnums=()):
+    """``jit(shard_map(fn))`` over the mesh's batch axes.
+
+    ``lanes`` says of each positional argument whether it is lane-leading
+    (every leaf split along its leading axis, batch-over-data) or
+    replicated (a lane index, one pair).  Every output is lane-leading and
+    stays distributed.  Each device runs ``fn`` on its own ``W / n`` lanes,
+    so the Pallas kernels inside are per-device calls: Mosaic cannot
+    partition a kernel automatically, which a GSPMD-partitioned program
+    would ask of it.
+    """
+    sh = lane_sharding(mesh)
+    shardings = tuple(sh if lane else NamedSharding(mesh, PartitionSpec())
+                      for lane in lanes)
+    body = jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(s.spec for s in shardings),
+                         out_specs=sh.spec, check_vma=False)
+    return jax.jit(body, in_shardings=shardings, out_shardings=sh,
+                   donate_argnums=donate_argnums)
+
+
 def compile_sharded_batch(batched, mesh):
     """``jit(shard_map(batched))`` over the mesh's batch axes.
 
@@ -139,7 +174,4 @@ def compile_sharded_batch(batched, mesh):
     materialised whole on one device) and the outputs stay distributed.
     The batch must be a multiple of :func:`batch_multiple` (``pad_batch``).
     """
-    sh = lane_sharding(mesh)
-    body = jax.shard_map(batched, mesh=mesh, in_specs=(sh.spec, sh.spec),
-                         out_specs=sh.spec, check_vma=False)
-    return jax.jit(body, in_shardings=(sh, sh), out_shardings=sh)
+    return compile_lane_program(batched, mesh, (True, True))

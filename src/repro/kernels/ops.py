@@ -23,11 +23,14 @@ from repro.kernels.bsi_adjoint import bsi_adjoint_pallas_planes
 from repro.kernels.bsi_fused import SCALAR_LANES, bsi_fused_pallas
 from repro.kernels.bsi_matmul import bsi_matmul_pallas
 from repro.kernels.bsi_separable import bsi_separable_pallas
+from repro.kernels.warp import (ROW_PAD, corner_indices, stored_rows,
+                                visit_counts, warp_trilinear_pallas)
 
 __all__ = ["PALLAS_MODES", "NO_KERNEL", "FUSED_SIM_KINDS", "FUSED_NO_TPU",
            "bsi_pallas", "bsi_adjoint_pallas", "fused_similarity_loss",
            "fused_supported", "default_interpret", "pick_block_tiles",
-           "pick_block_ctrl"]
+           "pick_block_ctrl", "warp_supported", "warp_vmem_bytes",
+           "warp_trilinear", "warp_tile_visits"]
 
 # Modes with a Pallas kernel.  The engine autotuner enumerates its Pallas
 # candidates from this; every other mode runs as its jnp form only.
@@ -366,3 +369,91 @@ def _fused_loss_jit(phi, moving, fixed, tile, *, sim_spec, compute_dtype,
     hb = -jnp.sum(pb * jnp.log(pb + eps))
     hab = -jnp.sum(pab * jnp.log(pab + eps))
     return 2.0 - (ha + hb) / (hab + eps)
+
+
+# --- trilinear warp (kernels.warp) -------------------------------------------
+
+
+def _warp_planes(vol_shape):
+    """``(Yo, Zo, Ys)``: output plane extents and stored moving-volume rows."""
+    _, y, z = (int(s) for s in vol_shape)
+    return (common.round_up(y, common.SUBLANE), common.round_up(z, common.LANE),
+            stored_rows(y))
+
+
+def warp_vmem_bytes(vol_shape):
+    """VMEM the warp kernel plans for: the single-buffered moving volume and
+    double-buffered displacement, output and partials planes."""
+    x = int(vol_shape[0])
+    yo, zo, ys = _warp_planes(vol_shape)
+    return x * common.plane_bytes(ys, zo) + 2 * 7 * common.plane_bytes(yo, zo)
+
+
+def warp_supported(vol_shape, dtype):
+    """Whether ``core.ffd.warp_volume`` can take the warp kernel: where
+    kernels compile, for a float32 moving volume whose VMEM plan fits
+    ``common.VMEM_BUDGET_BYTES``.  Elsewhere the XLA gather form runs."""
+    return (not default_interpret() and jnp.dtype(dtype) == jnp.float32
+            and warp_vmem_bytes(vol_shape) <= common.VMEM_BUDGET_BYTES)
+
+
+def warp_trilinear(moving, disp, *, partials=False):
+    """``trilinear_sample(moving, identity + disp)`` by the warp kernel.
+
+    ``moving`` is ``(X, Y, Z)`` and ``disp`` ``(X, Y, Z, 3)``, both float32.
+    With ``partials`` also returns the ``(X, Y, Z, 3)`` partials of the
+    warped volume with respect to ``disp`` (autodiff of the clamped XLA
+    form's values, computed from the same eight corners).
+    """
+    return _warp_jit(moving, disp, partials=partials,
+                     interpret=default_interpret())
+
+
+def _disp_planes(disp):
+    """The displacement channel-first, padded to ``(8, 128)`` planes."""
+    _, y, z, _ = disp.shape
+    yo, zo, _ = _warp_planes(disp.shape[:3])
+    return jnp.pad(jnp.moveaxis(disp, -1, 0),
+                   ((0, 0), (0, 0), (0, yo - y), (0, zo - z)))
+
+
+@functools.partial(jax.jit, static_argnames=("partials", "interpret"))
+def _warp_jit(moving, disp, *, partials, interpret):
+    shape = tuple(int(s) for s in moving.shape)
+    if disp.shape != shape + (3,):
+        raise ValueError(f"displacement {disp.shape} does not match volume "
+                         f"{shape}")
+    _, y, z = shape
+    _, zo, ys = _warp_planes(shape)
+    vol = jnp.pad(moving, ((0, 0), (ROW_PAD, ys - y - ROW_PAD), (0, zo - z)))
+    out = warp_trilinear_pallas(_disp_planes(disp), vol, shape=shape,
+                                partials=partials, interpret=interpret)
+    if not partials:
+        return out[:, :y, :z]
+    warped, grads = out
+    return warped[:, :y, :z], jnp.moveaxis(grads[:, :, :y, :z], 0, -1)
+
+
+def warp_tile_visits(disp):
+    """What the warp kernel visits per output register for the
+    ``(X, Y, Z, 3)`` field ``disp``, by the kernel's own bounds: the mean
+    z-registers, the mean source planes and row windows per z-register
+    visited, and the mean inner iterations (plane-window pairs summed over
+    z-registers).  The counter of how much of the field's local spread the
+    kernel pays for."""
+    d = _disp_planes(disp)
+    _, x, yo, zo = d.shape
+    ix = jnp.meshgrid(jnp.arange(x), jnp.arange(yo), jnp.arange(zo),
+                      indexing="ij")
+    cx, cy, cz = (i.astype(jnp.float32) + d[k] for k, i in enumerate(ix))
+    _, xs, rs, vs, _ = corner_indices(cx, cy, cz, ix[1] % common.SUBLANE,
+                                      tuple(int(s) for s in disp.shape[:3]))
+    tiles = (x, yo // common.SUBLANE, common.SUBLANE, zo // common.LANE,
+             common.LANE)
+    zregs, boxes = visit_counts(*[[a.reshape(tiles) for a in pair]
+                                  for pair in (xs, rs, vs)], axis=(2, 4))
+    visited = sum(jnp.sum(p > 0) for p, _ in boxes)
+    return {"zregs": float(jnp.mean(zregs)),
+            "planes": float(sum(jnp.sum(p) for p, _ in boxes) / visited),
+            "rows": float(sum(jnp.sum(w) for _, w in boxes) / visited),
+            "iterations": float(jnp.mean(sum(p * w for p, w in boxes)))}

@@ -8,6 +8,10 @@ deterministic (device work still runs; only the scheduler's notion of
 "now" is faked).
 """
 import asyncio
+import os
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import pytest
@@ -196,3 +200,45 @@ class TestAsyncFacade:
             solo = ffd_register(f, m, options=OPTS)
             np.testing.assert_allclose(np.asarray(served.warped),
                                        np.asarray(solo.warped), atol=1e-5)
+
+
+class TestMesh:
+    def test_mesh_lanes_match_one_device(self):
+        """Four fake CPU devices, two lanes each: the scheduler's programs
+        under ``shard_map`` (a splice into a lane held by another device, a
+        recycled lane, the final warp on one device) give what one device
+        gives.  A fresh process, so it holds with one device here."""
+        code = textwrap.dedent("""
+            import os, sys
+            os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+            os.environ["JAX_PLATFORMS"] = "cpu"
+            sys.path.insert(0, "tests")
+            import jax, numpy as np
+            from repro.engine import make_registration_mesh
+            from repro.engine.serve import RegistrationScheduler
+            from test_serve import OPTS, _mixed_pairs
+            assert jax.device_count() == 4, jax.devices()
+            pairs = _mixed_pairs(10)
+            res = []
+            for mesh in (None, make_registration_mesh()):
+                sched = RegistrationScheduler(OPTS, lanes=8, chunk=4,
+                                              mesh=mesh)
+                handles = [sched.submit(f, m) for f, m in pairs]
+                sched.run_until_idle()
+                res.append([h.result() for h in handles])
+            assert any(r.recycled for r in res[1])
+            for a, b in zip(*res):
+                np.testing.assert_allclose(np.asarray(b.warped),
+                                           np.asarray(a.warped), atol=1e-5)
+                np.testing.assert_allclose(np.asarray(b.params),
+                                           np.asarray(a.params), atol=1e-5)
+                np.testing.assert_allclose(b.losses, a.losses, rtol=1e-5)
+                assert b.steps == a.steps and b.recycled == a.recycled
+            print("MESH_SERVE_OK")
+        """)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, PYTHONPATH="src")
+        env.pop("XLA_FLAGS", None)  # the child pins its own before jax loads
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, env=env, cwd=root)
+        assert "MESH_SERVE_OK" in r.stdout, r.stderr[-2000:]

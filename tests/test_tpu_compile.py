@@ -28,12 +28,12 @@ DENSE = tuple((n - 3) * d for n, d in zip(GRID, TILE)) + (3,)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -42,8 +42,13 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture
@@ -59,6 +64,12 @@ def _spec(shape, sharding):
 
 def _kernel_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _kernel_names(txt):
+    """Names of the Pallas kernels (``tpu_custom_call`` instructions)."""
+    return set(re.findall(r"%(\w+?)(?:\.\d+)? = .*custom-call\(.*"
+                          r"tpu_custom_call", txt))
 
 
 @pytest.mark.parametrize("mode", ops.PALLAS_MODES)
@@ -82,7 +93,8 @@ def test_adjoint_kernel_compiles_at_porcine1(one_chip, compiled_kernels,
 def test_level_step_compiles_with_kernels(one_chip, compiled_kernels, mode,
                                           grad_impl):
     """The whole level-step gradient (BSI -> warp -> SSD, analytic adjoint)
-    compiles at porcine1 with both kernels inside."""
+    compiles at porcine1 with its three kernels inside: the BSI forward, its
+    adjoint and the warp."""
 
     def step(p, f, m):
         loss = ffd_level_loss(f, m, tile=TILE, bending_weight=5e-3,
@@ -91,7 +103,25 @@ def test_level_step_compiles_with_kernels(one_chip, compiled_kernels, mode,
 
     txt = _kernel_text(step, _spec(GRID, one_chip),
                        _spec(PORCINE1, one_chip), _spec(PORCINE1, one_chip))
-    assert txt.count("tpu_custom_call") >= 2
+    form = {"pallas": "separable", "matmul": "matmul"}[grad_impl]
+    assert _kernel_names(txt) == {f"bsi_{mode}", f"bsi_adjoint_{form}",
+                                  "warp_trilinear"}
+
+
+@pytest.mark.parametrize("partials", [False, True])
+@pytest.mark.parametrize("vol", [PORCINE1, (294, 130, 208)],
+                         ids=["porcine1", "phantom2"])
+def test_warp_kernel_compiles_at_the_cells(one_chip, compiled_kernels, vol,
+                                           partials):
+    """The warp kernel, its moving volume resident in VMEM, compiles at both
+    cells' volumes, values alone and with the partials, and leaves no
+    gather behind."""
+    assert ops.warp_supported(vol, jnp.float32)
+    txt = _kernel_text(
+        lambda m, d: ops.warp_trilinear(m, d, partials=partials),
+        _spec(vol, one_chip), _spec(vol + (3,), one_chip))
+    assert re.search(r"%warp_trilinear(?:\.\d+)? = .*custom-call", txt)
+    assert " gather(" not in txt
 
 
 @pytest.mark.parametrize("sim", ["ssd", "ncc", "lncc", "nmi"])
@@ -156,13 +186,23 @@ def _ops(txt):
 
 
 def _assert_stages(txt, nmi_exp):
+    """Every ``bsi_*`` kernel lies in ``bsi`` and the ``warp_trilinear``
+    kernel in ``warp``; no gather fusion is left in ``warp`` (the kernel
+    took the warp); NMI's exponentials lie in ``similarity``."""
     ops = _ops(txt)
-    gathers = [(l, op) for l, op, body in ops
-               if " fusion(" in l and " gather(" in body]
-    kernels = [(l, op) for l, op, _ in ops if "tpu_custom_call" in l]
-    assert gathers and kernels
-    assert all("warp" in _scopes(op) for _, op in gathers), gathers
-    assert all("bsi" in _scopes(op) for _, op in kernels), kernels
+    kernels = [(re.match(r"\s*(?:ROOT )?%(\w+)", l).group(1), op)
+               for l, op, _ in ops if "tpu_custom_call" in l]
+    names = {name for name, _ in kernels}
+    assert "warp_trilinear" in names, names
+    assert any(name.startswith("bsi_") for name in names), names
+    for name, op in kernels:
+        stage = "warp" if name == "warp_trilinear" else "bsi"
+        assert name.startswith("bsi_") or stage == "warp", name
+        assert stage in _scopes(op), (name, op)
+    warp_gathers = [(l, op) for l, op, body in ops
+                    if " fusion(" in l and " gather(" in body
+                    and "warp" in _scopes(op)]
+    assert not warp_gathers, warp_gathers
     if nmi_exp:
         exps = [(l, op) for l, op, body in ops
                 if " fusion(" in l and " exponential(" in body]
@@ -185,8 +225,9 @@ def test_level_step_ops_carry_their_stage(one_chip, compiled_kernels, sim,
                                           grad_impl):
     """The level step of the benchmark cells' autotuned forms
     (``separable/pallas/pallas`` and ``separable/pallas/jnp``), compiled
-    for the chip: every gather fusion lies in the ``warp`` scope, every
-    Pallas kernel in ``bsi``, and NMI's exponentials in ``similarity``."""
+    for the chip: the BSI kernels lie in the ``bsi`` scope, the warp kernel
+    in ``warp`` with no gather left there, and NMI's exponentials in
+    ``similarity``."""
     from repro.core.registration import _ffd_level_runner
 
     opts = _cell_options(sim, grad_impl)
@@ -200,7 +241,8 @@ def test_level_step_ops_carry_their_stage(one_chip, compiled_kernels, sim,
 def test_final_warp_is_one_program_with_stages(one_chip, compiled_kernels,
                                                sim):
     """``ffd_register``'s final displacement and warp compile as one
-    program whose gathers lie in ``warp`` and whose kernel lies in ``bsi``."""
+    program whose warp is the ``warp_trilinear`` kernel (no gathers) and
+    whose BSI kernel lies in ``bsi``."""
     from repro.engine.batch import compile_finish
 
     opts = _cell_options(sim, "pallas")
@@ -229,3 +271,38 @@ def test_kernel_names_differ_by_form(one_chip, compiled_kernels):
     assert names == {"separable": ["bsi_separable"], "matmul": ["bsi_matmul"],
                      "adjoint_separable": ["bsi_adjoint_separable"],
                      "adjoint_matmul": ["bsi_adjoint_matmul"]}
+
+
+# --- the scheduler's lane programs over a four-chip mesh ----------------------
+
+
+@pytest.mark.parametrize("program", ["chunk", "splice"])
+def test_scheduler_lane_programs_compile_over_a_mesh(topo, compiled_kernels,
+                                                     program):
+    """``RegistrationScheduler(mesh=)``'s chunk and splice programs, with the
+    lane arrays sharded over the four chips of a ``v5e:2x2``, compile with
+    their Pallas kernels inside (each chip makes its own calls: a
+    GSPMD-partitioned program cannot hold one)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.engine.batch import compile_level_chunk, compile_level_splice
+    from repro.engine.serve import lane_arrays
+    from repro.engine.shard import lane_sharding, make_registration_mesh
+
+    mesh = make_registration_mesh(devices=topo.devices)
+    lanes, rep = lane_sharding(mesh), NamedSharding(mesh, PartitionSpec())
+    opts = _cell_options("ssd", "pallas")
+    stacked = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=lanes),
+        jax.eval_shape(lambda: lane_arrays(8, SMALL, opts)))
+    if program == "chunk":
+        fn, args = compile_level_chunk(SMALL, opts, 2, mesh), stacked
+    else:
+        fn = compile_level_splice(SMALL, opts, mesh)
+        args = stacked + (jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
+                          _spec(SMALL_GRID, rep), _spec(SMALL, rep),
+                          _spec(SMALL, rep))
+    txt = fn.lower(*args).compile().as_text()
+    names = _kernel_names(txt)
+    assert "warp_trilinear" in names, names
+    assert any(n.startswith("bsi_adjoint_") for n in names), names
